@@ -211,10 +211,32 @@ def ef_topk(x: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     c = x.shape[-1]
     if k >= c:
         return x, jnp.zeros_like(x)
-    a = jnp.abs(x)
-    thresh = jax.lax.top_k(a, k)[0][..., k - 1:k]
-    dec = jnp.where(a >= thresh, x, jnp.zeros_like(x))
+    bits = kth_magnitude_bits(x, k)
+    keep = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.int32) >= bits
+    dec = jnp.where(keep, x, jnp.zeros_like(x))
     return dec, x - dec
+
+
+def kth_magnitude_bits(x: jax.Array, k: int) -> jax.Array:
+    """The int32 bit pattern of each row's kth-largest |x|, shape (..., 1).
+
+    Non-negative fp32 values order like their bit patterns, so the exact
+    kth magnitude is the largest pattern t with count(bits >= t) >= k —
+    built here one bit at a time, high to low (31 compare-and-count passes
+    over the row).  Compares, selects and a lane sum are all Mosaic lowers,
+    so the Pallas kernel runs this same function; ``lax.top_k`` does not
+    lower inside a TPU kernel.  Counts are fp32 sums of 0/1, exact for rows
+    up to 2^24 lanes."""
+    bits = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.int32)
+
+    def step(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - i)
+        n = jnp.sum(jnp.where(bits >= cand, 1.0, 0.0), axis=-1,
+                    keepdims=True)
+        return jnp.where(n >= k, cand, t)
+
+    t0 = jnp.zeros(x.shape[:-1] + (1,), jnp.int32)
+    return jax.lax.fori_loop(0, 31, step, t0)
 
 
 def ef_roundtrip(spec: CompressorSpec, x: jax.Array

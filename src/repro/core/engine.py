@@ -182,9 +182,8 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm import compressors as comm_mod
 from repro.configs.base import HierConfig, VRLConfig
 from repro.core import flat
@@ -762,6 +761,8 @@ class Engine(NamedTuple):
                                 # the round-END stale fold (hier: blocking
                                 # sync1 + conditional level-2 fold)
     backend: str = "fused"      # resolved executor: "fused" | "xla"
+    interpret: bool = False     # the fused kernels run as interpreted
+                                # Python (off-TPU/GPU), not compiled
     compressors: Any = (None, None)  # resolved (level-1, level-2)
                                      # CompressorSpecs (None = identity)
     set_membership: Any = None  # membership only: (state, (W,) mask) ->
@@ -840,6 +841,20 @@ class RoundCache:
     @property
     def cached_ks(self) -> Tuple[int, ...]:
         return tuple(sorted(self._jits))
+
+
+def _placed(init: Callable, mesh, specs: Callable) -> Callable:
+    """``init`` jitted to lay every buffer out as ``specs`` says on
+    ``mesh``: each device builds only its own rows, so a state of W
+    model-sized buffers never lands whole on one device first."""
+    def placed_init(params: Any, num_workers: int):
+        fn = functools.partial(init, num_workers=num_workers)
+        out = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                           specs(jax.eval_shape(fn, params)),
+                           is_leaf=lambda x: isinstance(x, P))
+        return jax.jit(fn, out_shardings=out)(params)
+
+    return placed_init
 
 
 def _ef_op(ops, comp: comm_mod.CompressorSpec, lanes: int, *, grid: bool,
@@ -1620,9 +1635,9 @@ def make_engine(cfg: VRLConfig, template: Any, *, mesh=None,
         def wrapped(state, *rest):
             sspec = _specs(state)
             in_specs = (sspec,) if gspec is None else (sspec, gspec)
-            return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                    out_specs=sspec,
-                                    check_vma=False)(state, *rest)
+            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=sspec,
+                                 check_vma=False)(state, *rest)
 
         return wrapped
 
@@ -1638,10 +1653,10 @@ def make_engine(cfg: VRLConfig, template: Any, *, mesh=None,
             return _core_diagnostics(state)
         out_specs = {k: (P(ax) if k == "drift_per_worker" else P())
                      for k in diag_keys}
-        return compat.shard_map(_core_diagnostics, mesh=mesh,
-                                in_specs=(_specs(state),),
-                                out_specs=out_specs,
-                                check_vma=False)(state)
+        return jax.shard_map(_core_diagnostics, mesh=mesh,
+                             in_specs=(_specs(state),),
+                             out_specs=out_specs,
+                             check_vma=False)(state)
     train_core = _sharded(_core_train, gspec=P(ax, shard_axis, None))
     round_core = _sharded(_core_round_overlap if cfg.overlap
                           else _core_round,
@@ -1657,7 +1672,7 @@ def make_engine(cfg: VRLConfig, template: Any, *, mesh=None,
             if not on_mesh:
                 return _core_round_begin(state)
             sspec = _specs(state)
-            return compat.shard_map(
+            return jax.shard_map(
                 _core_round_begin, mesh=mesh, in_specs=(sspec,),
                 out_specs=P(shard_axis, None), check_vma=False)(state)
 
@@ -1667,7 +1682,7 @@ def make_engine(cfg: VRLConfig, template: Any, *, mesh=None,
             if not on_mesh:
                 return _fold_overlap(state, xbar)
             sspec = _specs(state)
-            return compat.shard_map(
+            return jax.shard_map(
                 _fold_overlap, mesh=mesh,
                 in_specs=(sspec, P(shard_axis, None)), out_specs=sspec,
                 check_vma=False)(state, xbar)
@@ -1731,13 +1746,14 @@ def make_engine(cfg: VRLConfig, template: Any, *, mesh=None,
         return flat.unflatten_tree(fspec, jnp.mean(state.params, axis=0))
 
     return Engine(algorithm=cfg.algorithm, spec=fspec, algo=algo,
-                  init=init, train_step=train_step, local_step=local_step,
+                  init=_placed(init, mesh, _specs) if on_mesh else init,
+                  train_step=train_step, local_step=local_step,
                   sync=sync, average_model=avg_model,
                   params_tree=params_tree,
                   round_step=round_step, round_end=sync,
                   round_step_flat=round_step_flat,
                   round_begin=round_begin, round_fold=round_fold,
-                  backend=backend,
+                  backend=backend, interpret=interpret,
                   # store the resolve_pair form verbatim (level 2 is
                   # meaningless for flat algorithms but keeping the pair
                   # canonical means pair_meta(cfg) == pair_meta(engine
@@ -2306,9 +2322,9 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
         def wrapped(state, *rest):
             sspec = _specs(state)
             in_specs = (sspec,) if gspec is None else (sspec, gspec)
-            return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                    out_specs=sspec,
-                                    check_vma=False)(state, *rest)
+            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=sspec,
+                                 check_vma=False)(state, *rest)
 
         return wrapped
 
@@ -2330,10 +2346,10 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
         if meshless:
             return _core_diag_hier(state)
         out_specs = {k: P() for k in diag_keys}
-        return compat.shard_map(_core_diag_hier, mesh=mesh,
-                                in_specs=(_specs(state),),
-                                out_specs=out_specs,
-                                check_vma=False)(state)
+        return jax.shard_map(_core_diag_hier, mesh=mesh,
+                             in_specs=(_specs(state),),
+                             out_specs=out_specs,
+                             check_vma=False)(state)
 
     round_begin = round_fold = None
     if cfg.overlap:
@@ -2345,7 +2361,7 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
             if meshless:
                 return _core_round_begin(state, k)
             sspec = _specs(state)
-            return compat.shard_map(
+            return jax.shard_map(
                 functools.partial(_core_round_begin, k=k), mesh=mesh,
                 in_specs=(sspec,), out_specs=P(shard_axis, None),
                 check_vma=False)(state)
@@ -2357,7 +2373,7 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
             if meshless:
                 return _core_round_end_overlap(state, glob)
             sspec = _specs(state)
-            return compat.shard_map(
+            return jax.shard_map(
                 _core_round_end_overlap, mesh=mesh,
                 in_specs=(sspec, P(shard_axis, None)), out_specs=sspec,
                 check_vma=False)(state, glob)
@@ -2426,7 +2442,8 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
                                    jnp.mean(state.params, axis=(0, 1)))
 
     return Engine(algorithm=cfg.algorithm, spec=fspec, algo=algo,
-                  init=init, train_step=train_step, local_step=local_step,
+                  init=init if meshless else _placed(init, mesh, _specs),
+                  train_step=train_step, local_step=local_step,
                   sync=lambda s: sync_core(s), average_model=avg_model,
                   params_tree=params_tree,
                   sync1=lambda s: sync1_core(s),
@@ -2435,7 +2452,7 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
                   round_step=round_step, round_end=round_end,
                   round_step_flat=round_step_flat,
                   round_begin=round_begin, round_fold=round_fold,
-                  backend=backend,
+                  backend=backend, interpret=interpret,
                   compressors=(comp1, comp2),
                   set_membership=set_membership,
                   diagnostics=diagnostics)

@@ -29,6 +29,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 # Leaf paths use the checkpoint key style — share the formatter so the two
 # can never diverge (save_flat_state metadata must match the array keys).
@@ -165,10 +166,23 @@ def flatten_stacked(spec: FlatSpec, tree: Any,
     return vec.reshape(w, spec.rows, spec.lanes)
 
 
+def _gather_rows(buf: jax.Array, n_lead: int) -> jax.Array:
+    """Drop a row/lane split that an Explicit-axis mesh carries in the
+    buffer's type, keeping the ``n_lead`` worker dims' split: the leaf
+    slices cross shard boundaries, which typed shardings refuse.  Auto-axis
+    and meshless buffers pass through untouched."""
+    s = jax.typeof(buf).sharding
+    spec = tuple(s.spec) + (None,) * (buf.ndim - len(s.spec))
+    if all(a is None for a in spec[n_lead:]):
+        return buf
+    keep = P(*spec[:n_lead], *([None] * (buf.ndim - n_lead)))
+    return jax.sharding.reshard(buf, NamedSharding(s.mesh, keep))
+
+
 def unflatten_tree(spec: FlatSpec, buf: jax.Array,
                    cast: bool = True) -> Any:
     """(R, C) buffer -> single-model pytree (leaf dtypes restored)."""
-    vec = buf.reshape(-1)
+    vec = _gather_rows(buf, 0).reshape(-1)
     leaves = []
     for l in spec.leaves:
         piece = vec[l.offset:l.offset + l.size].reshape(l.shape)
@@ -180,7 +194,7 @@ def unflatten_stacked(spec: FlatSpec, buf: jax.Array,
                       cast: bool = True) -> Any:
     """(W, R, C) buffer -> worker-stacked pytree ((W, ...) leaves)."""
     w = buf.shape[0]
-    vec = buf.reshape(w, -1)
+    vec = _gather_rows(buf, 1).reshape(w, -1)
     leaves = []
     for l in spec.leaves:
         piece = vec[:, l.offset:l.offset + l.size].reshape((w,) + l.shape)

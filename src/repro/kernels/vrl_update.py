@@ -110,6 +110,29 @@ def vrl_sync_update(p: jax.Array, xbar: jax.Array, delta: jax.Array, *,
 # Worker-stacked (W, R, C) kernels for core/engine.py.  Grid = (W, R/block);
 # every buffer streams through VMEM exactly once per step.
 
+# Bytes the double-buffered row tiles of one kernel may take in VMEM: Mosaic
+# scopes 16 MiB of a v5e core's VMEM to a kernel by default, and the rest is
+# left for the fp32 temporaries of the kernel body.  At block 1024 x 256
+# fp32 lanes, six streamed operands fit; Adam's eight do not.
+_VMEM_BUDGET = 12 * 2**20
+
+
+def _row_block(block: int, *bufs) -> int:
+    """Tile height for a kernel over ``bufs`` (every operand and result
+    tiled by rows): the largest divisor of ``block`` — a multiple of 8, or
+    ``block`` itself — whose double-buffered tiles fit ``_VMEM_BUDGET``.
+
+    The flat layout (``FlatSpec.block``) stays as it is; only the grid gets
+    finer, which the elementwise and per-row math cannot see.  A lane dim
+    narrower than 128 still takes a full 128-lane tile row."""
+    row = sum(-(-b.shape[-1] // 128) * 128 * jnp.dtype(b.dtype).itemsize
+              for b in bufs)
+    cands = [t for t in range(block, 0, -1)
+             if block % t == 0 and (t == block or t % 8 == 0)]
+    return next((t for t in cands if 2 * t * row <= _VMEM_BUDGET),
+                cands[-1])
+
+
 def _grid_specs(w: int, r: int, c: int, block: int, n: int):
     """n identical (1, block, C) specs over a (W, R/block) grid."""
     del w, r
@@ -159,6 +182,7 @@ def fused_local_sgd(p, g, d=None, *, lr: float, wd: float = 0.0,
     w, r, c = p.shape
     use_delta, use_bias = d is not None, b is not None
     ins = (p, g) + ((d,) if use_delta else ()) + ((b,) if use_bias else ())
+    block = _row_block(block, *ins, p)
     specs = _grid_specs(w, r, c, block, len(ins))
     return pl.pallas_call(
         functools.partial(_fused_sgd_kernel, lr=lr, wd=wd,
@@ -195,6 +219,7 @@ def fused_local_momentum(p, g, d, m, *, lr: float, beta: float,
     use_delta, use_bias = d is not None, b is not None
     ins = ((p, g) + ((d,) if use_delta else ())
            + ((b,) if use_bias else ()) + (m,))
+    block = _row_block(block, *ins, p, m)
     specs = _grid_specs(w, r, c, block, len(ins))
     return pl.pallas_call(
         functools.partial(_fused_momentum_kernel, lr=lr, beta=beta, wd=wd,
@@ -242,6 +267,7 @@ def fused_local_adam(p, g, d, mu, nu, scal, *, lr: float, b1: float = 0.9,
     use_delta, use_bias = d is not None, b is not None
     ins = ((p, g) + ((d,) if use_delta else ())
            + ((b,) if use_bias else ()) + (mu, nu))
+    block = _row_block(block, *ins, p, mu, nu)
     specs = _grid_specs(w, r, c, block, len(ins)) + [_scal_spec(2)]
     return pl.pallas_call(
         functools.partial(_fused_adam_kernel, lr=lr, b1=b1, b2=b2, eps=eps,
@@ -316,15 +342,20 @@ def fused_local_adam_sm3(p, g, d, mu, row, col, scal, *, lr: float,
     w, r, c = p.shape
     shards = col.shape[-2]
     assert (r // block) % shards == 0, (r, block, shards)
-    tps = (r // block) // shards
     use_delta, use_bias = d is not None, b is not None
     ins = ((p, g) + ((d,) if use_delta else ())
-           + ((b,) if use_bias else ()) + (mu, row, col))
+           + ((b,) if use_bias else ()) + (mu, row))
+    block = _row_block(block, *ins, p, mu, row)
+    tps = (r // block) // shards
+    # the lane stat rides as (W, S, 1, C) so its block's last two dims are
+    # the array's own; the squeezed shard dim gives the kernel (1, 1, C)
+    ins = ins + (col.reshape(w, shards, 1, c),)
     n3 = len(ins) - 2                   # (W, R, C) operands
     specs = _grid_specs(w, r, c, block, n3)
     row_spec = pl.BlockSpec((1, block, 1), lambda wi, i: (wi, i, 0))
-    col_spec = pl.BlockSpec((1, 1, c), lambda wi, i: (wi, i // tps, 0))
-    return pl.pallas_call(
+    col_spec = pl.BlockSpec((1, pl.squeezed, 1, c),
+                            lambda wi, i: (wi, i // tps, 0, 0))
+    new_p, new_mu, new_row, new_col = pl.pallas_call(
         functools.partial(_fused_adam_sm3_kernel, lr=lr, b1=b1, b2=b2,
                           eps=eps, wd=wd, tps=tps, use_delta=use_delta,
                           use_bias=use_bias),
@@ -334,10 +365,11 @@ def fused_local_adam_sm3(p, g, d, mu, row, col, scal, *, lr: float,
         out_shape=[jax.ShapeDtypeStruct((w, r, c), p.dtype),
                    jax.ShapeDtypeStruct((w, r, c), mu.dtype),
                    jax.ShapeDtypeStruct(row.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(col.shape, jnp.float32)],
+                   jax.ShapeDtypeStruct(ins[-1].shape, jnp.float32)],
         input_output_aliases={0: 0, len(ins) - 3: 1, len(ins) - 2: 2},
         interpret=interpret,
     )(*ins, scal)
+    return new_p, new_mu, new_row, new_col.reshape(col.shape)
 
 
 def _fused_sync_kernel(p_ref, xb_ref, d_ref, s_ref, po_ref, do_ref):
@@ -359,6 +391,7 @@ def fused_sync_vrl(p, xbar, d, scal, *, block: int = 1024, interpret=None):
     if interpret is None:
         interpret = default_interpret()
     w, r, c = p.shape
+    block = _row_block(block, p, xbar, d, p, d)
     s3 = _grid_specs(w, r, c, block, 2)
     xb_spec = pl.BlockSpec((block, c), lambda wi, i: (i, 0))
     return pl.pallas_call(
@@ -399,6 +432,7 @@ def fused_sync_bvr(p, xbar, d, b, scal, *, beta: float, block: int = 1024,
     if interpret is None:
         interpret = default_interpret()
     w, r, c = p.shape
+    block = _row_block(block, p, xbar, d, b, p, d, b)
     s3 = _grid_specs(w, r, c, block, 3)
     xb_spec = pl.BlockSpec((block, c), lambda wi, i: (i, 0))
     return pl.pallas_call(
@@ -435,8 +469,10 @@ def fused_sync_bvr(p, xbar, d, b, scal, *, beta: float, block: int = 1024,
 # captures outside the kernel via the EF round-trip instead.
 
 def _wscal_spec(n: int):
-    """(1, n) per-worker row of a (W, n) operand, one row per grid worker."""
-    return pl.BlockSpec((1, n), lambda wi, i: (wi, 0))
+    """Per-worker (1, n) row of a (W, n) operand carried as (W, 1, n): the
+    block's last two dims are the array's own, as the TPU tiling rule asks,
+    and the squeezed worker dim hands the kernel a (1, n) ref."""
+    return pl.BlockSpec((pl.squeezed, 1, n), lambda wi, i: (wi, 0, 0))
 
 
 def _fold_overlap_kernel(*refs, use_delta: bool, use_bias: bool,
@@ -483,9 +519,6 @@ def _fold_call(p, xbar, pend, d, b, wscal, *, beta, capture, block,
     ins = ((p, xbar, pend) + ((d,) if use_delta else ())
            + ((b,) if use_bias else ()))
     n3 = len(ins) - 1               # (W, R, C) operands (all but xbar)
-    s3 = _grid_specs(w, r, c, block, n3)
-    xb_spec = pl.BlockSpec((block, c), lambda wi, i: (i, 0))
-    in_specs = [s3[0], xb_spec] + s3[1:] + [_wscal_spec(2)]
     n_out = 1 + use_delta + use_bias + capture
     out_shape = [jax.ShapeDtypeStruct((w, r, c), p.dtype)]
     if use_delta:
@@ -494,6 +527,10 @@ def _fold_call(p, xbar, pend, d, b, wscal, *, beta, capture, block,
         out_shape.append(jax.ShapeDtypeStruct((w, r, c), b.dtype))
     if capture:
         out_shape.append(jax.ShapeDtypeStruct((w, r, c), pend.dtype))
+    block = _row_block(block, *ins, *out_shape)
+    s3 = _grid_specs(w, r, c, block, n3)
+    xb_spec = pl.BlockSpec((block, c), lambda wi, i: (i, 0))
+    in_specs = [s3[0], xb_spec] + s3[1:] + [_wscal_spec(2)]
     # donate every state buffer onto its output: p→p', Δ→Δ', B→B',
     # pend→pend' (operand index: xbar sits at 1, pend at 2)
     aliases = {0: 0}
@@ -515,7 +552,7 @@ def _fold_call(p, xbar, pend, d, b, wscal, *, beta, capture, block,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-    )(*ins, wscal)
+    )(*ins, wscal.reshape(w, 1, 2))
 
 
 def fused_fold_overlap(p, xbar, pend, d, wscal, *, capture: bool = True,
@@ -584,10 +621,13 @@ def fused_fold_overlap_hier2(p, glob, pend2, d2, wscal, *,
     if interpret is None:
         interpret = default_interpret()
     pp, dd, r, c = p.shape
+    block = _row_block(block, p, glob, pend2, d2, p, d2,
+                       *((pend2,) if capture else ()))
     wspec = pl.BlockSpec((1, 1, block, c), lambda pi, i, di: (pi, di, i, 0))
     podspec = pl.BlockSpec((1, 1, block, c), lambda pi, i, di: (pi, 0, i, 0))
     gspec = pl.BlockSpec((block, c), lambda pi, i, di: (i, 0))
-    sspec = pl.BlockSpec((1, 2), lambda pi, i, di: (pi, 0))
+    # (P, 2) per-pod scalars ride as (P, 1, 2): see _wscal_spec
+    sspec = pl.BlockSpec((pl.squeezed, 1, 2), lambda pi, i, di: (pi, 0, 0))
     out_specs = [wspec, podspec] + ([podspec] if capture else [])
     out_shape = [jax.ShapeDtypeStruct(p.shape, p.dtype),
                  jax.ShapeDtypeStruct(d2.shape, d2.dtype)] \
@@ -601,7 +641,7 @@ def fused_fold_overlap_hier2(p, glob, pend2, d2, wscal, *,
         out_shape=out_shape,
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(p, glob, pend2, d2, wscal)
+    )(p, glob, pend2, d2, wscal.reshape(pp, 1, 2))
 
 
 def _easgd_worker_kernel(p_ref, c_ref, po_ref, *, a: float):
@@ -631,6 +671,7 @@ def fused_sync_easgd(p, xbar, center, *, a: float, na: float,
     if interpret is None:
         interpret = default_interpret()
     w, r, c = p.shape
+    block = _row_block(block, p, center, p)
     pspec = _grid_specs(w, r, c, block, 1)[0]
     cspec = pl.BlockSpec((block, c), lambda wi, i: (i, 0))
     new_p = pl.pallas_call(
@@ -669,11 +710,9 @@ def fused_sync_easgd(p, xbar, center, *, a: float, na: float,
 # by ``repro.comm.compressors.compress`` for byte measurement — the engine hot
 # path only ever needs the decompressed payload and the residual.
 #
-# Note on top-k selection: the kernel body uses ``jax.lax.top_k`` over the
-# lane axis for the per-row threshold (kth magnitude).  Interpret mode
-# (CPU) executes it directly; on compiled TPU backends a Mosaic without
-# lane-axis top_k support would need a bitonic network here — the jnp
-# executor (``kernels/xla_update``) is the drop-in fallback either way.
+# Top-k selection finds each row's kth magnitude by a bisection over the
+# int32 bit pattern of |x| (``compressors.kth_magnitude_bits``), because
+# ``lax.top_k`` has no Mosaic lowering.
 
 def _ef_kernel(*refs, mode: str, k: int, use_ref: bool, use_ef: bool):
     # the round-trip math is the CANONICAL repro.comm implementation —
@@ -704,6 +743,9 @@ def _ef_call(p, ref, e, *, mode: str, k: int, block: int, interpret,
         interpret = default_interpret()
     use_ref, use_ef = ref is not None, e is not None
     c = p.shape[-1]
+    block = _row_block(block, p, *((ref,) if use_ref else ()),
+                       *((e, e) if use_ef else ()),
+                       jax.ShapeDtypeStruct(p.shape, jnp.float32))
     if grid_kind == "flat":
         w, r, _ = p.shape
         grid = (w, r // block)
@@ -806,6 +848,7 @@ def fused_hier_local_sgd(p, g, d1, d2, *, lr: float, wd: float = 0.0,
     if interpret is None:
         interpret = default_interpret()
     pp, dd, r, c = p.shape
+    block = _row_block(block, p, g, d1, d2, p)
     specs = _grid4_specs(block, c, 3)
     return pl.pallas_call(
         functools.partial(_hier_sgd_kernel, lr=lr, wd=wd),
@@ -837,6 +880,7 @@ def fused_hier_local_momentum(p, g, d1, d2, m, *, lr: float, beta: float,
     if interpret is None:
         interpret = default_interpret()
     pp, dd, r, c = p.shape
+    block = _row_block(block, p, g, d1, d2, m, p, m)
     specs = _grid4_specs(block, c, 4)
     return pl.pallas_call(
         functools.partial(_hier_momentum_kernel, lr=lr, beta=beta, wd=wd,
@@ -876,6 +920,7 @@ def fused_hier_local_adam(p, g, d1, d2, mu, nu, scal, *, lr: float,
     if interpret is None:
         interpret = default_interpret()
     pp, dd, r, c = p.shape
+    block = _row_block(block, p, g, d1, d2, mu, nu, p, mu, nu)
     specs = _grid4_specs(block, c, 5)
     return pl.pallas_call(
         functools.partial(_hier_adam_kernel, lr=lr, b1=b1, b2=b2, eps=eps,
@@ -938,13 +983,16 @@ def fused_hier_local_adam_sm3(p, g, d1, d2, mu, row, col, scal, *,
     pp, dd, r, c = p.shape
     shards = col.shape[-2]
     assert (r // block) % shards == 0, (r, block, shards)
+    block = _row_block(block, p, g, d1, d2, mu, row, p, mu, row)
     tps = (r // block) // shards
     specs = _grid4_specs(block, c, 4)
     row_spec = pl.BlockSpec((1, 1, block, 1),
                             lambda pi, di, i: (pi, di, i, 0))
-    col_spec = pl.BlockSpec((1, 1, 1, c),
-                            lambda pi, di, i: (pi, di, i // tps, 0))
-    return pl.pallas_call(
+    # (P, D, S, 1, C) lane stat: see fused_local_adam_sm3
+    col4 = col.reshape(pp, dd, shards, 1, c)
+    col_spec = pl.BlockSpec((1, 1, pl.squeezed, 1, c),
+                            lambda pi, di, i: (pi, di, i // tps, 0, 0))
+    new_p, new_mu, new_row, new_col = pl.pallas_call(
         functools.partial(_hier_adam_sm3_kernel, lr=lr, b1=b1, b2=b2,
                           eps=eps, wd=wd, tps=tps),
         grid=(pp, dd, r // block),
@@ -954,10 +1002,11 @@ def fused_hier_local_adam_sm3(p, g, d1, d2, mu, row, col, scal, *,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype),
                    jax.ShapeDtypeStruct(mu.shape, mu.dtype),
                    jax.ShapeDtypeStruct(row.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(col.shape, jnp.float32)],
+                   jax.ShapeDtypeStruct(col4.shape, jnp.float32)],
         input_output_aliases={0: 0, 4: 1, 5: 2},
         interpret=interpret,
-    )(p, g, d1, d2, mu, row, col, scal)
+    )(p, g, d1, d2, mu, row, col4, scal)
+    return new_p, new_mu, new_row, new_col.reshape(col.shape)
 
 
 def _hier_sync1_kernel(p_ref, xb_ref, d_ref, s_ref, po_ref, do_ref):
@@ -979,6 +1028,7 @@ def fused_sync_hier1(p, xbar_pod, d1, scal, *, block: int = 1024,
     if interpret is None:
         interpret = default_interpret()
     pp, dd, r, c = p.shape
+    block = _row_block(block, p, xbar_pod, d1, p, d1)
     specs = _grid4_specs(block, c, 2)
     return pl.pallas_call(
         _hier_sync1_kernel,
@@ -1016,6 +1066,7 @@ def fused_sync_hier2(p, glob, d2, scal, *, block: int = 1024,
     if interpret is None:
         interpret = default_interpret()
     pp, dd, r, c = p.shape
+    block = _row_block(block, p, glob, d2, p, d2)
     wspec = pl.BlockSpec((1, 1, block, c), lambda pi, i, di: (pi, di, i, 0))
     podspec = pl.BlockSpec((1, 1, block, c), lambda pi, i, di: (pi, 0, i, 0))
     gspec = pl.BlockSpec((block, c), lambda pi, i, di: (i, 0))

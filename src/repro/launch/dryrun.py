@@ -1,8 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-#   512 placeholder host devices back both the 16x16 single-pod mesh (first
-#   256 devices) and the 2x16x16 multi-pod mesh.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
+# ^ MUST precede any jax import: jax locks the platform and the device count
+#   on first init.  The dry-run compiles on the host and never takes an
+#   accelerator; 512 placeholder host devices back both the 16x16
+#   single-pod mesh (first 256 devices) and the 2x16x16 multi-pod mesh.
 
 """Multi-pod dry-run driver.
 
@@ -35,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.comm import compressors as comm_mod
 from repro.configs.base import (EngineConfig, HierConfig, InputShape,
                                 MeshConfig, VRLConfig)
@@ -43,7 +45,7 @@ from repro.configs import registry
 from repro.core import engine as engine_mod
 from repro.core import schedule as schedule_mod
 from repro.launch import roofline as rl
-from repro.launch.mesh import (CHIPS_PER_POD, HBM_PER_CHIP,
+from repro.launch.mesh import (CHIPS_PER_POD, HBM_PER_CHIP, make_mesh,
                                make_production_mesh)
 from repro.models import transformer
 from repro.models.param import abstract as abstract_params
@@ -55,8 +57,8 @@ from repro.train.train_loop import make_train_step
 # --------------------------------------------------------------------- mesh
 def build_mesh(mesh_cfg: MeshConfig):
     n = math.prod(mesh_cfg.shape)
-    return compat.make_mesh(mesh_cfg.shape, mesh_cfg.axis_names,
-                            devices=jax.devices()[:n])
+    return make_mesh(mesh_cfg.shape, mesh_cfg.axis_names,
+                     devices=jax.devices()[:n])
 
 
 def _data_axes(mesh_cfg: MeshConfig):
@@ -483,7 +485,7 @@ def lower_one(arch_id: str, shape_id: str, *, multi_pod: bool,
         name += f"/{tag}"
 
     eng_spec = None               # flat-buffer layout (for wire-bytes)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if fn_kind in ("train", "local", "sync", "sync1", "sync2", "round"):
             fused = engine_mod.resolve_backend(vrl_cfg) != "reference"
             with warnings.catch_warnings():
@@ -514,7 +516,7 @@ def lower_one(arch_id: str, shape_id: str, *, multi_pod: bool,
                     shard_axis=sh_ax, shards=vrl_cfg.engine.shards)
             else:
                 st_spec = state_specs(cfg, mesh_cfg, vrl_cfg)
-            sts = compat.shardings(mesh, st_spec)
+            sts = sh.shardings(mesh, st_spec)
             extra = 2 if cfg.frontend == "codec" else 1
             tok_spec = batch_sharding_spec(
                 mesh_cfg, shape.global_batch // mesh_cfg.num_workers,
@@ -544,23 +546,22 @@ def lower_one(arch_id: str, shape_id: str, *, multi_pod: bool,
                     (rk, *ins["tokens"].shape), ins["tokens"].dtype)
                 slb = jax.ShapeDtypeStruct(
                     (rk, *ins["labels"].shape), ins["labels"].dtype)
-                tks = compat.shardings(mesh, P(None, *tok_spec))
-                lbs = compat.shardings(mesh, P(None, *lab_spec))
+                tks = sh.shardings(mesh, P(None, *tok_spec))
+                lbs = sh.shardings(mesh, P(None, *lab_spec))
                 fn = jax.jit(bundle.round_step, donate_argnums=(0,),
                              in_shardings=(sts, tks, lbs),
                              out_shardings=(sts,
-                                            compat.shardings(mesh,
-                                                             P(None))))
+                                            sh.shardings(mesh, P(None))))
                 lowered = fn.lower(state_abs, stk, slb)
             else:
                 step = (bundle.train_step if fn_kind == "train"
                         else bundle.local_step)
                 fn = jax.jit(step,
                              in_shardings=(sts,
-                                           compat.shardings(mesh, tok_spec),
-                                           compat.shardings(mesh, lab_spec)),
+                                           sh.shardings(mesh, tok_spec),
+                                           sh.shardings(mesh, lab_spec)),
                              out_shardings=(sts,
-                                            compat.shardings(mesh, P())))
+                                            sh.shardings(mesh, P())))
                 lowered = fn.lower(state_abs, ins["tokens"], ins["labels"])
             mf = _model_flops_train(cfg, shape)
             if fn_kind in ("sync", "sync1", "sync2"):
@@ -583,9 +584,9 @@ def lower_one(arch_id: str, shape_id: str, *, multi_pod: bool,
             c_spec = cache_specs(cfg, mesh_cfg, shape.global_batch,
                                  seq_len=min(eff, shape.seq_len))
             fn = jax.jit(prefill_fn,
-                         in_shardings=compat.shardings(
+                         in_shardings=sh.shardings(
                              mesh, (pspec, tok_spec)),
-                         out_shardings=compat.shardings(
+                         out_shardings=sh.shardings(
                              mesh, (logits_spec, c_spec)))
             lowered = fn.lower(params_abs, ins["tokens"])
             mf = _model_flops_prefill(cfg, shape)
@@ -605,9 +606,9 @@ def lower_one(arch_id: str, shape_id: str, *, multi_pod: bool,
             vax = _maybe(tuple(mesh_cfg.tensor_axes), cfg.vocab_size, mesh_cfg)
             logits_spec = P(bax, None, vax)
             fn = jax.jit(serve_fn,
-                         in_shardings=compat.shardings(
+                         in_shardings=sh.shardings(
                              mesh, (pspec, c_spec, tok_spec, P())),
-                         out_shardings=compat.shardings(
+                         out_shardings=sh.shardings(
                              mesh, (logits_spec, c_spec)))
             lowered = fn.lower(params_abs, ins["cache"], ins["tokens"],
                                ins["pos"])

@@ -8,8 +8,15 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axes: the engine places its buffers with
+    shard_map specs and leaves the rest of the program to the partitioner
+    (``jax.make_mesh`` defaults to Explicit axes, which type every split)."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

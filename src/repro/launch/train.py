@@ -1,9 +1,11 @@
-"""Training driver (real execution, CPU-scale).
+"""Training driver (real execution, on the host CPU or a TPU).
 
 Runs VRL-SGD (or a baseline, or two-level hierarchical VRL-SGD) on a
-selectable architecture's reduced or full config with the synthetic non-iid
-LM pipeline, periodic checkpointing, and average-model evaluation — the
-same code path the dry-run lowers for the production mesh.
+selectable architecture's reduced (``--smoke``) or full config with the
+synthetic non-iid LM pipeline, periodic checkpointing, and average-model
+evaluation — the same code path the dry-run lowers for the production mesh.
+On a TPU, ``chip_smoke.py`` at the repo root runs this driver at published
+widths.
 
 Execution is ROUND-based by default (``EngineConfig.round_scan``): each
 communication period runs as ONE jit dispatch (k scanned local steps +
@@ -81,6 +83,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import json
+import math
 import time
 
 import jax
@@ -98,6 +101,7 @@ from repro.data import assigned_token_stream
 from repro.data import partition as partition_mod
 from repro.fault import FaultSchedule
 from repro.launch import mesh as mesh_mod
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import diagnostics as obs_diag
 from repro.obs import metrics as obs_metrics
@@ -170,6 +174,20 @@ def _validate_args(args) -> None:
     if args.profile_round and not args.round:
         raise SystemExit("--profile-round traces a compiled round; drop "
                          "--no-round")
+
+
+def _worker_devices(params) -> list:
+    """Ids of the devices holding each worker's rows of a flat params
+    buffer ((W, R, C), or pod-major (P, D, R, C) flattened), so a run
+    records where its workers really live."""
+    lead = params.shape[:-2]
+    ids = np.arange(math.prod(lead)).reshape(lead)
+    held = [set() for _ in range(ids.size)]
+    for dev, idx in params.sharding.devices_indices_map(
+            params.shape).items():
+        for w in ids[tuple(idx[:len(lead)])].ravel():
+            held[w].add(dev.id)
+    return [sorted(h) for h in held]
 
 
 def _build_faults(args) -> FaultSchedule | None:
@@ -390,6 +408,7 @@ def main(argv=None) -> int:
                     help="directory for the --profile-round trace")
     args = ap.parse_args(argv)
     _validate_args(args)
+    enable_compile_cache()
 
     cfg = (registry.smoke_arch(args.arch) if args.smoke
            else registry.get_arch(args.arch))
@@ -566,12 +585,19 @@ def main(argv=None) -> int:
             "k2": hier.k2 if hier else None,
             "lr": args.lr, "seed": args.seed,
             "backend": args.backend, "resolved_backend": resolved,
+            "interpret": (bundle.engine.interpret
+                          if bundle.engine is not None else None),
+            "device": {"platform": jax.devices()[0].platform,
+                       "kind": jax.devices()[0].device_kind,
+                       "count": jax.device_count()},
             "round_scan": bool(args.round), "overlap": bool(args.overlap),
             "membership": bool(membership), "guard": bool(args.guard),
             "shards": args.shards,
             "compress": comm_mod.pair_meta(comps),
             "faults": faults.describe() if faults is not None else None,
             "n_params": int(n_params),
+            "worker_devices": (_worker_devices(state.params)
+                               if bundle.engine is not None else None),
             "wire": wire,
             "client_store": store.meta() if store is not None else None,
         })
@@ -914,6 +940,7 @@ def main(argv=None) -> int:
                     and not profiled:
                 jax.profiler.start_trace(args.profile_dir)
                 profiling = True
+            t_round = time.perf_counter()
             with phase("round"):
                 if gmul is not None:
                     state, losses = fault_round_fn(state, toks, labels,
@@ -924,6 +951,7 @@ def main(argv=None) -> int:
                     # timed rounds block here so the sample is the real
                     # round wall-clock, not the dispatch latency
                     losses = jax.block_until_ready(losses)
+            round_s = time.perf_counter() - t_round
             if profiling:
                 jax.profiler.stop_trace()
                 profiling, profiled = False, True
@@ -1003,6 +1031,7 @@ def main(argv=None) -> int:
             t += rk
             r += 1
             mw.emit("round", t=t, r=r, k=rk, loss=loss_r,
+                    seconds=round_s,
                     wire_bytes=None if wire is None
                     else wire["wire_bytes"])
             mw.emit("sync", t=t, r=r, k_eff=rk,

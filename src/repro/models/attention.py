@@ -22,11 +22,14 @@ NEG_INF = -1e30
 
 def attention_defs(cfg: ModelConfig) -> dict:
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    # explicit fan-in scales: the default reads fan-in from dim -2, which
+    # here is a head count (q/k/v) or head_dim (o), not the contraction
+    s_in, s_out = d ** -0.5, (h * hd) ** -0.5
     defs = {
-        "wq": ParamDef((d, h, hd), ("embed", "heads", None)),
-        "wk": ParamDef((d, kv, hd), ("embed", "kv_heads", None)),
-        "wv": ParamDef((d, kv, hd), ("embed", "kv_heads", None)),
-        "wo": ParamDef((h, hd, d), ("heads", None, "embed")),
+        "wq": ParamDef((d, h, hd), ("embed", "heads", None), scale=s_in),
+        "wk": ParamDef((d, kv, hd), ("embed", "kv_heads", None), scale=s_in),
+        "wv": ParamDef((d, kv, hd), ("embed", "kv_heads", None), scale=s_in),
+        "wo": ParamDef((h, hd, d), ("heads", None, "embed"), scale=s_out),
     }
     if cfg.qkv_bias:
         defs["bq"] = ParamDef((h, hd), ("heads", None), init="zeros")

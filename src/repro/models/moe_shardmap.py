@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import moe as moe_ref
 
@@ -98,7 +97,7 @@ def moe_mlp_shardmap(cfg: ModelConfig, p: dict, x: jax.Array, mesh,
         aux = jax.lax.pmean(aux, data_axis)
         return y, aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(data_axis, None),            # tokens
                   P(None, None),                 # router (replicated)

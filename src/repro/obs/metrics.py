@@ -12,7 +12,9 @@ Event vocabulary the training driver emits (consumers must tolerate
 unknown events — the set grows):
 
   run_start    stream header: ``meta`` run-description dict
-  round        a compiled round committed: t, r, k, loss, wire_bytes
+  round        a compiled round committed: t, r, k, loss, wire_bytes,
+               seconds (host wall-clock of the dispatch; it ends in a
+               block on the losses, and round 1 includes the compile)
   sync         the round's sync collective: wire_bytes, participants
   diag         algorithm-health diagnostics (``Engine.diagnostics``):
                drift_sq_mean/drift_max/drift_per_worker, zeta_sq_proxy,

@@ -1,7 +1,6 @@
 from repro.sharding.specs import (  # noqa: F401
     axis_rules,
     batch_spec,
-    make_mesh,
     partition_specs,
     shardings,
     spec_for,

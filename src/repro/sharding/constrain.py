@@ -2,9 +2,8 @@
 from __future__ import annotations
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
-from repro.compat import get_abstract_mesh
 
 
 def maybe_constrain(x, *spec_parts):

@@ -15,8 +15,10 @@ from repro.configs.base import MeshConfig, ModelConfig
 from repro.models.param import ParamDef, is_def
 
 
-def make_mesh(mesh_cfg: MeshConfig) -> Mesh:
-    return jax.make_mesh(mesh_cfg.shape, mesh_cfg.axis_names)
+def shardings(mesh: Mesh, spec_tree):
+    """PartitionSpec pytree -> NamedSharding pytree on ``mesh``."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def axis_rules(cfg: ModelConfig, mesh_cfg: MeshConfig) -> dict:
@@ -64,13 +66,6 @@ def partition_specs(defs, cfg: ModelConfig, mesh_cfg: MeshConfig):
     """Pytree of PartitionSpec mirroring a ParamDef pytree."""
     rules = axis_rules(cfg, mesh_cfg)
     return jax.tree.map(lambda d: spec_for(d, rules), defs, is_leaf=is_def)
-
-
-def shardings(defs, cfg: ModelConfig, mesh_cfg: MeshConfig, mesh: Mesh):
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s),
-        partition_specs(defs, cfg, mesh_cfg),
-        is_leaf=lambda x: isinstance(x, P))
 
 
 def worker_stacked_spec(spec: P, mesh_cfg: MeshConfig) -> P:

@@ -29,7 +29,8 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
+    from repro.sharding import specs as sh
     from repro.configs import registry
     from repro.configs.base import HierConfig, VRLConfig
     from repro.core import engine as engine_mod
@@ -37,7 +38,7 @@ SCRIPT = textwrap.dedent("""
     from repro.train.train_loop import make_train_step
 
     cfg = registry.smoke_arch("granite-3-2b")
-    mesh = compat.make_mesh((2, 4), ("pod", "data"), devices=jax.devices())
+    mesh = make_mesh((2, 4), ("pod", "data"), devices=jax.devices())
     axes = ("pod", "data")
 
     def all_reduce_groups(hlo):
@@ -56,12 +57,12 @@ SCRIPT = textwrap.dedent("""
         return groups
 
     def lower(bundle, state_abs, name, fn, with_data=False):
-        sts = compat.shardings(
+        sts = sh.shardings(
             mesh, engine_mod.state_partition_specs(state_abs, axes))
         if with_data:
-            dspec = compat.shardings(mesh, P(axes, None, None))
+            dspec = sh.shardings(mesh, P(axes, None, None))
             c = jax.jit(fn, in_shardings=(sts, dspec, dspec),
-                        out_shardings=(sts, compat.shardings(mesh, P()))
+                        out_shardings=(sts, sh.shardings(mesh, P()))
                         ).lower(state_abs, toks, toks).compile()
         else:
             c = jax.jit(fn, in_shardings=(sts,), out_shardings=sts
@@ -72,7 +73,7 @@ SCRIPT = textwrap.dedent("""
 
     toks = jax.ShapeDtypeStruct((8, 2, 32), jnp.int32)
     out = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for alg in ["vrl_sgd", "ssgd"]:
             vrl = VRLConfig(algorithm=alg, comm_period=4, learning_rate=0.01,
                             update_backend="fused")

@@ -167,10 +167,10 @@ SCRIPT_SHARDED = textwrap.dedent("""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
     from repro.configs.base import EngineConfig, VRLConfig
     from repro.core import make_engine
     from repro.core.engine import state_partition_specs
+    from repro.sharding import specs as sh
 
     # (2 workers x 4 shards) mesh: every engine buffer's row dim splits
     # over "shard", workers over "data" — the round-closing sync must STAY
@@ -188,7 +188,7 @@ SCRIPT_SHARDED = textwrap.dedent("""
     def place(e, st):
         specs = state_partition_specs(st, ("data",), shard_axis="shard",
                                       shards=4)
-        return jax.device_put(st, compat.shardings(mesh, specs))
+        return jax.device_put(st, sh.shardings(mesh, specs))
 
     state = place(eng, eng.init(p0, 2))
     out = {}

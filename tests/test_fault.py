@@ -408,7 +408,7 @@ def test_guard_catches_scale_poison_and_rolls_back(capsys):
     train.main(["--smoke", "--steps", "6", "--workers", "4",
                 "--batch", "2", "--seq", "32", "--k", "2",
                 "--lr", "0.05", "--guard", "--max-retries", "2",
-                "--faults", "scale@1:2:1e3", "--log-every", "1"])
+                "--faults", "scale@1:2:1e4", "--log-every", "1"])
     out = capsys.readouterr().out
     assert "gradient fault in round [2, 4)" in out
     assert "loss blow-up" in out                 # the trend branch fired,

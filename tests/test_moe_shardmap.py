@@ -12,7 +12,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, json
     import jax, jax.numpy as jnp
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.configs import registry
     from repro.models import moe as moe_ref
     from repro.models.moe_shardmap import moe_mlp_shardmap
@@ -22,7 +22,7 @@ SCRIPT = textwrap.dedent("""
     cfg = registry.smoke_arch("phi3.5-moe-42b-a6.6b")
     cfg = dataclasses.replace(cfg, num_experts=8, experts_per_token=2,
                               capacity_factor=8.0, num_shared_experts=0)
-    mesh = compat.make_mesh((2, 4), ("data", "model"), devices=jax.devices())
+    mesh = make_mesh((2, 4), ("data", "model"), devices=jax.devices())
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     d, ff, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
     p = {"router": jax.random.normal(ks[0], (d, e)) * 0.1,
@@ -31,7 +31,7 @@ SCRIPT = textwrap.dedent("""
          "w_down": jax.random.normal(ks[3], (e, ff, d)) * 0.05}
     x = jax.random.normal(ks[4], (64, d))
     y_ref, _ = moe_ref.moe_mlp(cfg, p, x)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(lambda p, x: moe_mlp_shardmap(cfg, p, x, mesh))
         y_sm, _ = fn(p, x)
         coll = rl.collective_bytes(fn.lower(p, x).compile().as_text())
