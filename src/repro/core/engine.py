@@ -91,6 +91,12 @@ compiled HLO aliases every state buffer in place (asserted in
 exactly one sync collective per k steps
 (``tests/test_engine_collectives.py``).
 
+Every local step runs under ``jax.named_scope("engine.local_update")`` and
+every sync (flat, and each hierarchical level) under ``"engine.sync"``, so
+the compiled ops carry those names in their HLO ``op_name`` and a profile
+can split the round's device time by layer (``obs.scopemap``,
+``tests/test_scopes.py``).
+
 Rounds take k from the leading axis of the grads stack, so a stagewise
 ``CommSchedule`` (``core/schedule.py``, ``VRLConfig.comm_schedule``) just
 feeds differently-sized stacks per stage: ``RoundCache`` keys one compiled
@@ -192,6 +198,7 @@ from repro.core.types import (CommState, HierCommState, HierState,
                               MemberState, OverlapState, WorkerState)
 from repro.kernels import vrl_update as vu
 from repro.kernels import xla_update as xu
+from repro.obs import scopemap
 from repro.optim.optimizers import AdamState, SM3Pair, make_inner
 
 
@@ -813,7 +820,8 @@ class RoundCache:
 
     ``compiles`` counts actual traces (incremented at trace time), so a
     retrace of an existing k — which would break the "one executable per
-    stage" contract — is visible too.
+    stage" contract — is visible too.  Each new executable is recorded in
+    ``obs.scopemap``, which maps its ops to their named scopes.
     """
 
     def __init__(self, round_step: Callable, *, donate: bool = True):
@@ -835,6 +843,9 @@ class RoundCache:
                 return self._round(s, *rest)
 
             fn = jax.jit(traced, donate_argnums=self._donate)
+            # Compiled ahead of the call below, which reuses the
+            # executable; its HLO names each op's scope (obs.scopemap).
+            scopemap.record(fn.lower(state, *stacks).compile())
             self._jits[k] = fn
         return fn(state, *stacks)
 
@@ -1206,6 +1217,7 @@ def make_engine(cfg: VRLConfig, template: Any, *, mesh=None,
 
     # ------------------------------------------------- core step functions
     # These see LOCAL shards (W_local, R, C) when shard_mapped.
+    @jax.named_scope("engine.local_update")
     def _core_local(state: FlatWorkerState, g: jax.Array) -> FlatWorkerState:
         if algo.grad_all_reduce:
             if comp is not None:
@@ -1270,6 +1282,7 @@ def make_engine(cfg: VRLConfig, template: Any, *, mesh=None,
                        ref=xbar)
         return xbar, state._replace(comm=cm)
 
+    @jax.named_scope("engine.sync")
     def _core_sync(state: FlatWorkerState) -> FlatWorkerState:
         if algo.sync == "none":
             return state._replace(last_sync=state.step)
@@ -1897,6 +1910,7 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
                              comm=comm, overlap=overlap, member=member)
 
     # ------------------------------------------------- core step functions
+    @jax.named_scope("engine.local_update")
     def _core_local(state: HierFlatState, g: jax.Array) -> HierFlatState:
         if kind == "sgd":
             new_p = ops.fused_hier_local_sgd(
@@ -1932,6 +1946,7 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
         return state._replace(params=new_p, inner=new_inner,
                               step=state.step + 1)
 
+    @jax.named_scope("engine.sync")
     def _core_sync1(state: HierFlatState) -> HierFlatState:
         k_eff = jnp.maximum(state.step - state.last_sync1, 1
                             ).astype(jnp.float32)
@@ -1954,6 +1969,7 @@ def _make_hier_engine(cfg: VRLConfig, algo: AlgoSpec, fspec: flat.FlatSpec,
         return state._replace(params=new_p, delta1=new_d1,
                               last_sync1=state.step)
 
+    @jax.named_scope("engine.sync")
     def _core_sync2(state: HierFlatState) -> HierFlatState:
         # Assumes a level-1 sync at this step: params ARE the pod averages,
         # so the global mean needs only the cross-pod axis.

@@ -14,7 +14,8 @@ owns that layout:
                        (concrete arrays or ShapeDtypeStructs).
   * flatten/unflatten — exact (pad/slice only, no arithmetic) conversions
                        between the pytree world and (R, C) / (W, R, C)
-                       worker-stacked buffers.
+                       worker-stacked buffers, traced under the named
+                       scopes ``flat.flatten`` / ``flat.unflatten``.
 
 Tiling policy (``choose_block``): lanes are fixed at a VPU-friendly multiple
 of 128; the row count is padded up to a multiple of the largest block in
@@ -152,6 +153,7 @@ def flatten_tree(spec: FlatSpec, tree: Any,
     return vec.reshape(spec.rows, spec.lanes)
 
 
+@jax.named_scope("flat.flatten")
 def flatten_stacked(spec: FlatSpec, tree: Any,
                     dtype: Optional[Any] = None) -> jax.Array:
     """Worker-stacked pytree (leading axis W on every leaf) -> (W, R, C)."""
@@ -179,6 +181,7 @@ def _gather_rows(buf: jax.Array, n_lead: int) -> jax.Array:
     return jax.sharding.reshard(buf, NamedSharding(s.mesh, keep))
 
 
+@jax.named_scope("flat.unflatten")
 def unflatten_tree(spec: FlatSpec, buf: jax.Array,
                    cast: bool = True) -> Any:
     """(R, C) buffer -> single-model pytree (leaf dtypes restored)."""
@@ -190,6 +193,7 @@ def unflatten_tree(spec: FlatSpec, buf: jax.Array,
     return jax.tree_util.tree_unflatten(spec.treedef, leaves)
 
 
+@jax.named_scope("flat.unflatten")
 def unflatten_stacked(spec: FlatSpec, buf: jax.Array,
                       cast: bool = True) -> Any:
     """(W, R, C) buffer -> worker-stacked pytree ((W, ...) leaves)."""
@@ -210,6 +214,7 @@ def unflatten_stacked(spec: FlatSpec, buf: jax.Array,
 # worker axis split (P, D) — so these are exact reshapes around the stacked
 # converters and the same FlatSpec round-trips both.
 
+@jax.named_scope("flat.flatten")
 def flatten_grid(spec: FlatSpec, tree: Any,
                  dtype: Optional[Any] = None) -> jax.Array:
     """Grid-stacked pytree ((P, D, ...) leaves) -> (P, D, R, C)."""
@@ -220,6 +225,7 @@ def flatten_grid(spec: FlatSpec, tree: Any,
     return buf.reshape(p, d, spec.rows, spec.lanes)
 
 
+@jax.named_scope("flat.unflatten")
 def unflatten_grid(spec: FlatSpec, buf: jax.Array,
                    cast: bool = True) -> Any:
     """(P, D, R, C) buffer -> grid-stacked pytree ((P, D, ...) leaves)."""

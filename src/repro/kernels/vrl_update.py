@@ -31,6 +31,10 @@ State buffers are donated (``input_output_aliases``) so every update is
 in-place: the kernels read each block exactly once before overwriting it,
 and XLA falls back to a copy when a donated buffer has another consumer.
 
+Every ``pallas_call`` is named (``vrl_local_sgd``, ``vrl_sync``, ...): the
+compiled custom call takes that name, so a profile names the kernel rather
+than the jitted function around it.
+
 ``block``/``interpret`` come from the engine config (``configs.base
 .EngineConfig``); the (R, C) layout and auto block choice from
 ``core/flat.py``.
@@ -83,6 +87,7 @@ def vrl_local_update(p: jax.Array, g: jax.Array, delta: jax.Array, *,
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r, c), p.dtype),
+        name="vrl_local_update",
         interpret=interpret,
     )(p, g, delta)
 
@@ -102,6 +107,7 @@ def vrl_sync_update(p: jax.Array, xbar: jax.Array, delta: jax.Array, *,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((r, c), p.dtype),
                    jax.ShapeDtypeStruct((r, c), delta.dtype)],
+        name="vrl_sync_update",
         interpret=interpret,
     )(p, xbar, delta)
 
@@ -192,6 +198,7 @@ def fused_local_sgd(p, g, d=None, *, lr: float, wd: float = 0.0,
         out_specs=specs[0],
         out_shape=jax.ShapeDtypeStruct((w, r, c), p.dtype),
         input_output_aliases={0: 0},
+        name="vrl_local_sgd",
         interpret=interpret,
     )(*ins)
 
@@ -231,6 +238,7 @@ def fused_local_momentum(p, g, d, m, *, lr: float, beta: float,
         out_shape=[jax.ShapeDtypeStruct((w, r, c), p.dtype),
                    jax.ShapeDtypeStruct((w, r, c), m.dtype)],
         input_output_aliases={0: 0, len(ins) - 1: 1},
+        name="vrl_local_momentum",
         interpret=interpret,
     )(*ins)
 
@@ -279,6 +287,7 @@ def fused_local_adam(p, g, d, mu, nu, scal, *, lr: float, b1: float = 0.9,
                    jax.ShapeDtypeStruct((w, r, c), mu.dtype),
                    jax.ShapeDtypeStruct((w, r, c), nu.dtype)],
         input_output_aliases={0: 0, len(ins) - 2: 1, len(ins) - 1: 2},
+        name="vrl_local_adam",
         interpret=interpret,
     )(*ins, scal)
 
@@ -367,6 +376,7 @@ def fused_local_adam_sm3(p, g, d, mu, row, col, scal, *, lr: float,
                    jax.ShapeDtypeStruct(row.shape, jnp.float32),
                    jax.ShapeDtypeStruct(ins[-1].shape, jnp.float32)],
         input_output_aliases={0: 0, len(ins) - 3: 1, len(ins) - 2: 2},
+        name="vrl_local_adam_sm3",
         interpret=interpret,
     )(*ins, scal)
     return new_p, new_mu, new_row, new_col.reshape(col.shape)
@@ -402,6 +412,7 @@ def fused_sync_vrl(p, xbar, d, scal, *, block: int = 1024, interpret=None):
         out_shape=[jax.ShapeDtypeStruct((w, r, c), p.dtype),
                    jax.ShapeDtypeStruct((w, r, c), d.dtype)],
         input_output_aliases={0: 0, 2: 1},
+        name="vrl_sync",
         interpret=interpret,
     )(p, xbar, d, scal)
 
@@ -444,6 +455,7 @@ def fused_sync_bvr(p, xbar, d, b, scal, *, beta: float, block: int = 1024,
                    jax.ShapeDtypeStruct((w, r, c), d.dtype),
                    jax.ShapeDtypeStruct((w, r, c), b.dtype)],
         input_output_aliases={0: 0, 2: 1, 3: 2},
+        name="vrl_sync_bvr",
         interpret=interpret,
     )(p, xbar, d, b, scal)
 
@@ -551,6 +563,7 @@ def _fold_call(p, xbar, pend, d, b, wscal, *, beta, capture, block,
         out_specs=[s3[0]] * n_out,
         out_shape=out_shape,
         input_output_aliases=aliases,
+        name="vrl_fold_overlap",
         interpret=interpret,
     )(*ins, wscal.reshape(w, 1, 2))
 
@@ -640,6 +653,7 @@ def fused_fold_overlap_hier2(p, glob, pend2, d2, wscal, *,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases={0: 0},
+        name="vrl_fold_overlap_hier2",
         interpret=interpret,
     )(p, glob, pend2, d2, wscal.reshape(pp, 1, 2))
 
@@ -681,6 +695,7 @@ def fused_sync_easgd(p, xbar, center, *, a: float, na: float,
         out_specs=pspec,
         out_shape=jax.ShapeDtypeStruct((w, r, c), p.dtype),
         input_output_aliases={0: 0},
+        name="easgd_sync_worker",
         interpret=interpret,
     )(p, center)
     flat2 = pl.BlockSpec((block, c), lambda i: (i, 0))
@@ -691,6 +706,7 @@ def fused_sync_easgd(p, xbar, center, *, a: float, na: float,
         out_specs=flat2,
         out_shape=jax.ShapeDtypeStruct((r, c), center.dtype),
         input_output_aliases={0: 0},
+        name="easgd_sync_center",
         interpret=interpret,
     )(center, xbar)
     return new_p, new_c
@@ -775,6 +791,7 @@ def _ef_call(p, ref, e, *, mode: str, k: int, block: int, interpret,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
+        name=f"vrl_ef_{mode}",
         interpret=interpret,
     )(*ins)
     if use_ef:
@@ -857,6 +874,7 @@ def fused_hier_local_sgd(p, g, d1, d2, *, lr: float, wd: float = 0.0,
         out_specs=specs[0],
         out_shape=jax.ShapeDtypeStruct(p.shape, p.dtype),
         input_output_aliases={0: 0},
+        name="vrl_hier_local_sgd",
         interpret=interpret,
     )(p, g, d1, d2)
 
@@ -892,6 +910,7 @@ def fused_hier_local_momentum(p, g, d1, d2, m, *, lr: float, beta: float,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype),
                    jax.ShapeDtypeStruct(m.shape, m.dtype)],
         input_output_aliases={0: 0, 4: 1},
+        name="vrl_hier_local_momentum",
         interpret=interpret,
     )(p, g, d1, d2, m)
 
@@ -933,6 +952,7 @@ def fused_hier_local_adam(p, g, d1, d2, mu, nu, scal, *, lr: float,
                    jax.ShapeDtypeStruct(mu.shape, mu.dtype),
                    jax.ShapeDtypeStruct(nu.shape, nu.dtype)],
         input_output_aliases={0: 0, 4: 1, 5: 2},
+        name="vrl_hier_local_adam",
         interpret=interpret,
     )(p, g, d1, d2, mu, nu, scal)
 
@@ -1004,6 +1024,7 @@ def fused_hier_local_adam_sm3(p, g, d1, d2, mu, row, col, scal, *,
                    jax.ShapeDtypeStruct(row.shape, jnp.float32),
                    jax.ShapeDtypeStruct(col4.shape, jnp.float32)],
         input_output_aliases={0: 0, 4: 1, 5: 2},
+        name="vrl_hier_local_adam_sm3",
         interpret=interpret,
     )(p, g, d1, d2, mu, row, col4, scal)
     return new_p, new_mu, new_row, new_col.reshape(col.shape)
@@ -1038,6 +1059,7 @@ def fused_sync_hier1(p, xbar_pod, d1, scal, *, block: int = 1024,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype),
                    jax.ShapeDtypeStruct(d1.shape, d1.dtype)],
         input_output_aliases={0: 0, 2: 1},
+        name="vrl_sync_hier1",
         interpret=interpret,
     )(p, xbar_pod, d1, scal)
 
@@ -1078,5 +1100,6 @@ def fused_sync_hier2(p, glob, d2, scal, *, block: int = 1024,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype),
                    jax.ShapeDtypeStruct(d2.shape, d2.dtype)],
         input_output_aliases={0: 0},
+        name="vrl_sync_hier2",
         interpret=interpret,
     )(p, glob, d2, scal)

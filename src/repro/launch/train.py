@@ -64,8 +64,11 @@ non-finite worker count), ``membership`` / ``rollback`` / ``cohort`` /
 ``run_end`` record with the final averaged-model loss plus wall-clock
 phase-timer p50/p95s (the phases are the host-visible boundaries —
 data staging, the round dispatch+block, eval, diag, gather/scatter,
-checkpoint; local-steps/sync/fold cannot be split apart, they live
-inside ONE compiled dispatch).  The diagnostics pass is one read-only
+checkpoint; local-steps/sync/fold live inside ONE compiled dispatch,
+which only the device trace's named scopes split).  Each phase is also
+a profiler host span (``obs.timers.phase``) and each round runs under a
+``StepTraceAnnotation``, so a ``--profile-round`` trace shows the
+training loop beside the device ops.  The diagnostics pass is one read-only
 jit over the flat engine state, SEPARATE from the compiled round — the
 one-sync-all-reduce HLO contract is untouched.  ``--invariant-alarm
 1e-3`` feeds a tripped Σ Δ / Σ B residual into the ``--guard``
@@ -81,7 +84,7 @@ jax.profiler trace around round N.  Render a stream (or diff two) with
 from __future__ import annotations
 
 import argparse
-import contextlib
+import functools
 import json
 import math
 import time
@@ -105,7 +108,7 @@ from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import diagnostics as obs_diag
 from repro.obs import metrics as obs_metrics
-from repro.obs.timers import PhaseTimers
+from repro.obs import timers as timers_mod
 from repro.train.loss import cross_entropy_lm
 from repro.train.train_loop import make_train_step
 
@@ -602,9 +605,8 @@ def main(argv=None) -> int:
             "client_store": store.meta() if store is not None else None,
         })
         print(f"metrics: streaming JSONL events -> {args.metrics}")
-    timers = PhaseTimers() if mw.active else None
-    phase = (timers.phase if timers is not None
-             else (lambda name: contextlib.nullcontext()))
+    timers = timers_mod.PhaseTimers() if mw.active else None
+    phase = functools.partial(timers_mod.phase, timers=timers)
     diag_fn = None
     if bundle.engine is not None and (diag_wanted or mw.active):
         diag_fn = jax.jit(bundle.engine.diagnostics)
@@ -941,7 +943,8 @@ def main(argv=None) -> int:
                 jax.profiler.start_trace(args.profile_dir)
                 profiling = True
             t_round = time.perf_counter()
-            with phase("round"):
+            with jax.profiler.StepTraceAnnotation("round", step_num=r), \
+                    phase("round"):
                 if gmul is not None:
                     state, losses = fault_round_fn(state, toks, labels,
                                                    jnp.asarray(gmul))

@@ -73,16 +73,19 @@ def attend_full(cfg: ModelConfig, p: dict, x: jax.Array,
     k = _repeat_kv(k, group)
     v = _repeat_kv(v, group)
 
-    scale = cfg.head_dim ** -0.5
-    scores = jnp.einsum("...qhk,...shk->...hqs", q, k).astype(jnp.float32) * scale
-    qi = positions[..., None, :, None]   # (..., 1, q, 1)
-    ki = positions[..., None, None, :]   # (..., 1, 1, s)
-    mask = ki <= qi                      # (..., 1, q, s) broadcast over heads
-    if window is not None:
-        mask = mask & (ki > qi - window)
-    scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("...hqs,...shk->...qhk", probs, v)
+    # the (..., H, S, S) part: scores, mask, softmax and P·V
+    with jax.named_scope("attention"):
+        scale = cfg.head_dim ** -0.5
+        scores = jnp.einsum("...qhk,...shk->...hqs", q, k
+                            ).astype(jnp.float32) * scale
+        qi = positions[..., None, :, None]   # (..., 1, q, 1)
+        ki = positions[..., None, None, :]   # (..., 1, 1, s)
+        mask = ki <= qi                  # (..., 1, q, s) broadcast over heads
+        if window is not None:
+            mask = mask & (ki > qi - window)
+        scores = jnp.where(mask, scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("...hqs,...shk->...qhk", probs, v)
     out = jnp.einsum("...qhk,hkd->...qd", out, p["wo"])
     if return_kv:
         return out, kv_cache

@@ -110,6 +110,7 @@ def embed_inputs(cfg: ModelConfig, params: dict, inputs: jax.Array) -> jax.Array
     return params["embed"][inputs]
 
 
+@jax.named_scope("head")
 def logits_out(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -138,8 +139,9 @@ def forward(cfg: ModelConfig, params: dict, inputs: jax.Array,
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                params["layers"], unroll=unroll)
     if return_hidden:
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), \
-            aux / cfg.num_layers
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return x, aux / cfg.num_layers
     return logits_out(cfg, params, x), aux / cfg.num_layers
 
 

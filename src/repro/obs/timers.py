@@ -7,6 +7,12 @@ diagnostics, gather/scatter, membership updates, checkpointing.  The
 summary reports per-phase sample count, total seconds and nearest-rank
 p50/p95 milliseconds.
 
+``phase(name, timers)`` is the training loop's one way to mark a phase:
+it always opens a ``jax.profiler.TraceAnnotation`` (a host span on the
+profiler's clock, next to the device ops of a ``--profile-round`` trace;
+close to free when no trace runs) and adds a wall-clock sample only when a
+``PhaseTimers`` is given.
+
 Self-contained on purpose: ``src/repro`` must not import ``benchmarks``
 (the percentile helper there is the same nearest-rank convention).
 """
@@ -15,7 +21,9 @@ from __future__ import annotations
 import math
 import time
 from contextlib import contextmanager
-from typing import Dict, List
+from typing import Dict, List, Optional
+
+import jax
 
 
 def percentile(samples: List[float], q: float) -> float:
@@ -55,3 +63,15 @@ class PhaseTimers:
                 "p95_ms": round(1e3 * percentile(s, 95), 3),
             }
         return out
+
+
+@contextmanager
+def phase(name: str, timers: Optional[PhaseTimers] = None):
+    """Run the block as the training phase ``name``: a profiler host span,
+    and a sample in ``timers`` when one is given."""
+    with jax.profiler.TraceAnnotation(name):
+        if timers is None:
+            yield
+        else:
+            with timers.phase(name):
+                yield

@@ -1,10 +1,14 @@
-"""Losses: causal LM cross-entropy (fp32 logsumexp) and classifier CE."""
+"""Losses: causal LM cross-entropy (fp32 logsumexp) and classifier CE.
+
+The LM losses run under the named scope ``head``, with the final norm and
+the vocabulary product of ``models/transformer.py``."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("head")
 def cross_entropy_lm(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """logits (..., S, V) vs next-token labels (..., S) — mean NLL."""
     logits = logits.astype(jnp.float32)
@@ -25,6 +29,7 @@ def accuracy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     return jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
 
 
+@jax.named_scope("head")
 def chunked_cross_entropy_lm(hidden: jax.Array, head: jax.Array,
                              labels: jax.Array, chunk: int = 8192,
                              head_is_embed: bool = False) -> jax.Array:

@@ -112,6 +112,7 @@ def make_train_step(model_cfg: ModelConfig, vrl_cfg: VRLConfig,
     fused backend (shard_map worker axis for the flat all-reduce)."""
     alg = get_algorithm(vrl_cfg.algorithm)
 
+    @jax.named_scope("model")
     def loss_fn(params, tokens, labels):
         if chunked_ce:
             hidden, aux = transformer.forward(model_cfg, params, tokens,

@@ -94,6 +94,102 @@ def test_phase_timers_percentiles():
     assert percentile([5.0], 95) == 5.0
 
 
+def test_phase_spans_land_in_a_profiler_trace(tmp_path):
+    """``timers.phase`` writes a host span on the profiler's clock whether
+    or not a ``PhaseTimers`` samples it, and a round's
+    ``StepTraceAnnotation`` sits beside it: the training loop's phases
+    next to the device ops of a ``--profile-round`` trace."""
+    import glob
+
+    from repro.obs import timers as timers_mod
+    t = PhaseTimers()
+    f = jax.jit(lambda x: x * 2)
+    f(jnp.ones(4)).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.StepTraceAnnotation("round", step_num=3):
+        with timers_mod.phase("data", t):
+            x = jnp.arange(4.0)
+        with timers_mod.phase("eval"):
+            f(x).block_until_ready()
+    jax.profiler.stop_trace()
+    pb = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = {}
+    for plane in jax.profiler.ProfileData.from_file(pb[0]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("data", "eval", "round"):
+                    spans[e.name] = dict(e.stats)
+    assert set(spans) == {"data", "eval", "round"}
+    assert spans["round"].get("step_num") == 3
+    # only the phase given a PhaseTimers was sampled
+    assert list(t.summary()) == ["data"]
+
+
+def test_scopemap_parses_op_names_of_compiled_hlo():
+    """``obs.scopemap.parse`` keys each instruction of a compiled module's
+    text (TPU layouts, ``ROOT``, a custom call's backend config) by name.
+    An instruction without an ``op_name`` takes that of the loop that runs
+    its computation; at the entry level it is left out."""
+    from repro.obs import scopemap
+    text = "\n".join([
+        "HloModule jit_traced, "
+        "entry_computation_layout={(f32[8]{0})->f32[8]{0}}",
+        "%fused_computation.1 (param_0.1: f32[8]) -> f32[8] {",
+        '  ROOT %add.3 = f32[8]{0:T(256)} add(%param_0.1, %param_0.1), '
+        'metadata={op_name="jit(traced)/engine.sync/add" source_line=4}',
+        "}",
+        "%body.2 (t: (s32[], f32[8])) -> (s32[], f32[8]) {",
+        "  %t = (s32[], f32[8]) parameter(0)",
+        "  %copy-done.6 = f32[8]{0:T(256)S(1)} copy-done(%copy-start.6)",
+        '  ROOT %tuple.1 = (s32[], f32[8]) tuple(%i, %copy-done.6), '
+        'metadata={op_name="jit(traced)/while/body/closed_call/model/add"}',
+        "}",
+        "ENTRY %main.9 (p: f32[8]) -> f32[8] {",
+        "  %p = f32[8]{0:T(256)} parameter(0)",
+        "  %while.3 = (s32[], f32[8]) while(%t0), condition=%cond.4, "
+        'body=%body.2, metadata={op_name="jit(traced)/model/while"}',
+        '  %fusion.585 = f32[2,14]{1,0:T(2,128)} fusion(%p), kind=kLoop, '
+        'calls=%fused_computation.1, metadata={op_type="x" '
+        'op_name="jit(traced)/while/body/vmap(transpose(jvp(model)))/'
+        'attention/dot_general" source_file="a.py" source_line=12}',
+        '  %copy.83 = f32[8]{0:T(1,128)} copy(%fusion.585)',
+        '  ROOT %vrl_sync.1 = f32[8]{0} custom-call(%copy.83), '
+        'custom_call_target="tpu_custom_call", backend_config={"k": "v"}, '
+        'metadata={op_name="jit(traced)/engine.sync/vrl_sync/pallas_call"}',
+        "}"])
+    assert scopemap.parse(text) == {
+        "add.3": "jit(traced)/engine.sync/add",
+        "fusion.585": "jit(traced)/while/body/vmap(transpose(jvp(model)))/"
+                      "attention/dot_general",
+        "vrl_sync.1": "jit(traced)/engine.sync/vrl_sync/pallas_call",
+        "while.3": "jit(traced)/model/while",
+        "tuple.1": "jit(traced)/while/body/closed_call/model/add",
+        # the loop's own path, not its sibling's
+        "copy-done.6": "jit(traced)/model/while",
+        "t": "jit(traced)/model/while"}
+
+
+def test_round_cache_records_its_executable():
+    """Each round executable ``RoundCache`` compiles becomes
+    ``scopemap.latest()``, and ``op_paths`` reads its scopes."""
+    from repro.core.engine import RoundCache
+    from repro.obs import scopemap
+
+    def round_step(state, xs):
+        with jax.named_scope("engine.sync"):
+            return state + xs.sum(0), xs.mean()
+
+    rc = RoundCache(round_step)
+    state, _ = rc(jnp.zeros(4), jnp.ones((2, 4)))
+    first = scopemap.latest()
+    assert first is not None
+    assert any("engine.sync" in p for p in scopemap.op_paths().values())
+    state, _ = rc(state, jnp.ones((2, 4)))  # the same k: nothing new
+    assert scopemap.latest() is first
+    rc(state, jnp.ones((3, 4)))             # a new k: its own executable
+    assert scopemap.latest() is not first and rc.compiles == 2
+
+
 def test_report_summarize_and_diff(tmp_path):
     path = str(tmp_path / "m.jsonl")
     with MetricsWriter(path, run_meta={"arch": "a", "algorithm": "vrl_sgd",

@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,6 +110,14 @@ CASES = {
 }
 
 
+def _kernel_line(hlo: str, kernel: str):
+    """The compiled custom call named after ``kernel``'s ``pallas_call``
+    (None where there is none)."""
+    m = re.search(rf"^\s*(?:ROOT )?%{kernel}(?:\.\d+)? = .*"
+                  r'custom_call_target="tpu_custom_call".*$', hlo, re.M)
+    return m.group(0) if m else None
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_engine_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = CASES[name]
@@ -116,6 +125,10 @@ def test_engine_kernel_compiles_for_v5e(one_chip, name):
             for s in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo, name
+    # the custom call takes the kernel's name, which a profile shows
+    kernel = "vrl_" + name.replace("_sharded", "").replace("sync_vrl",
+                                                            "sync")
+    assert _kernel_line(hlo, kernel), (name, kernel)
 
 
 def test_bf16_moments_compile_for_v5e(one_chip):
@@ -150,3 +163,8 @@ def test_round_compiles_for_v5e_at_published_widths(one_chip):
     hlo = jax.jit(bundle.round_step, donate_argnums=(0,)).lower(
         state, toks, toks).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 2
+    # each kernel under its name, inside the engine's named scope
+    for kernel, scope in (("vrl_local_sgd", "engine.local_update"),
+                          ("vrl_sync", "engine.sync")):
+        line = _kernel_line(hlo, kernel)
+        assert line and f"{scope}/{kernel}/pallas_call" in line, kernel
