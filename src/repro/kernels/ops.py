@@ -7,7 +7,7 @@ correctness validation) and False on TPU.
 """
 from __future__ import annotations
 
-import math
+import functools
 from typing import Optional
 
 import jax
@@ -25,34 +25,22 @@ def _default_interpret() -> bool:
 # ------------------------------------------------------------ attention op
 def mha_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
-              block_q: int = 128, block_k: int = 128,
+              scale: Optional[float] = None,
+              block_q: Optional[int] = None, block_k: Optional[int] = None,
               interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, S, H, D); k, v: (B, S, KVH, D) -> (B, S, H, D).
 
-    Repeats kv heads to match q (GQA) and pads S to a block multiple.
+    Grouped-query heads stay as they are (q head h reads kv head
+    h // (H // KVH)); the kernel pads S to its block.  ``scale`` defaults to
+    D ** -0.5; blocks to ``flash_attention.block_sizes``.
     """
     if interpret is None:
         interpret = _default_interpret()
-    b, s, h, d = q.shape
-    kvh = k.shape[2]
-    group = h // kvh
-    if group > 1:
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
-    blk = math.gcd(block_q, block_k)
-    pad = (-s) % max(block_q, block_k)
-    qt = jnp.moveaxis(q, 2, 1).reshape(b * h, s, d)
-    kt = jnp.moveaxis(k, 2, 1).reshape(b * h, s, d)
-    vt = jnp.moveaxis(v, 2, 1).reshape(b * h, s, d)
-    if pad:
-        qt = jnp.pad(qt, ((0, 0), (0, pad), (0, 0)))
-        kt = jnp.pad(kt, ((0, 0), (0, pad), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, pad), (0, 0)))
-    out = fa.flash_attention(qt, kt, vt, causal=causal, window=window,
-                             block_q=block_q, block_k=block_k,
-                             interpret=interpret)
-    out = out[:, :s].reshape(b, h, s, d)
-    return jnp.moveaxis(out, 1, 2)
+    one = functools.partial(fa.flash_attention, causal=causal, window=window,
+                            scale=scale, block_q=block_q, block_k=block_k,
+                            interpret=interpret)
+    out = jax.vmap(one)(*(jnp.swapaxes(t, 1, 2) for t in (q, k, v)))
+    return jnp.swapaxes(out, 1, 2)
 
 
 # ------------------------------------------------------------------ ssd op

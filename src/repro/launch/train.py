@@ -61,12 +61,14 @@ the Δ-dispersion ζ² proxy for the paper's inter-worker gradient
 variance, Σ Δ / Σ B invariant residuals, EF-residual and moment norms,
 non-finite worker count), ``membership`` / ``rollback`` / ``cohort`` /
 ``checkpoint`` / ``restore`` / ``fault`` events as they happen, and a
-``run_end`` record with the final averaged-model loss plus wall-clock
-phase-timer p50/p95s (the phases are the host-visible boundaries —
-data staging, the round dispatch+block, eval, diag, gather/scatter,
-checkpoint; local-steps/sync/fold live inside ONE compiled dispatch,
-which only the device trace's named scopes split).  Each phase is also
-a profiler host span (``obs.timers.phase``) and each round runs under a
+``run_end`` record with the final averaged-model loss, the compiled
+round's attention executor (``flash``/``dense`` and its kernel count)
+and wall-clock phase-timer p50/p95s (the phases are the host-visible
+boundaries — data staging, the round dispatch+block, eval, diag,
+gather/scatter, checkpoint; local-steps/sync/fold live inside ONE
+compiled dispatch, which only the device trace's named scopes split).
+Each phase is also a profiler host span (``obs.timers.phase``) and each
+round runs under a
 ``StepTraceAnnotation``, so a ``--profile-round`` trace shows the
 training loop beside the device ops.  The diagnostics pass is one read-only
 jit over the flat engine state, SEPARATE from the compiled round — the
@@ -108,6 +110,7 @@ from repro.launch.cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import diagnostics as obs_diag
 from repro.obs import metrics as obs_metrics
+from repro.obs import scopemap
 from repro.obs import timers as timers_mod
 from repro.train.loss import cross_entropy_lm
 from repro.train.train_loop import make_train_step
@@ -1091,6 +1094,13 @@ def main(argv=None) -> int:
                  f"{'s' if round_fn.compiles != 1 else ''} "
                  f"(k={list(round_fn.cached_ks)})")
         end_meta.update(rounds=r, round_executables=round_fn.compiles)
+        # the compiled round's attention core, read from its HLO text
+        # after the last round (obs.scopemap)
+        attn = scopemap.attention_executor()
+        if attn is not None:
+            end_meta["attention"] = attn
+            extra += (f", attention {attn['executor']} "
+                      f"({attn['kernels']} kernels)")
     print(f"done: {args.steps} steps in {time.time()-t0:.1f}s{extra}")
     # final metrics off the average model over one fresh batch — the
     # chaos CI gate compares --loss-out across faulted/clean runs

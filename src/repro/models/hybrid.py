@@ -33,10 +33,11 @@ def _fuse(p: dict, ya: jax.Array, ys: jax.Array, eps: float) -> jax.Array:
 
 def hybrid_forward(cfg: ModelConfig, p: dict, x: jax.Array,
                    positions: jax.Array, window=None,
-                   return_cache: bool = False, cache_len: int = 0):
+                   return_cache: bool = False, cache_len: int = 0,
+                   contiguous: bool = False):
     w = window if window is not None else cfg.attn_window
     ya = attention.attend_full(cfg, p["attn"], x, positions, window=w,
-                               return_kv=return_cache)
+                               return_kv=return_cache, contiguous=contiguous)
     ys = ssm.ssm_forward(cfg, p["ssm"], x, return_cache=return_cache)
     if return_cache:
         ya, kv = ya

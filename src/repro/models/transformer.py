@@ -72,13 +72,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32):
 
 # ------------------------------------------------------------------- blocks
 def _block(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
-           window: Optional[int]):
-    """One layer. Returns (x, aux_loss)."""
+           window: Optional[int], contiguous: bool = False):
+    """One layer. Returns (x, aux_loss).  ``contiguous``: ``positions`` are
+    0..seq-1 (``attention.attend_full``)."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.family in ("dense", "vlm", "audio", "moe"):
         x = x + attention.attend_full(
             cfg, p["attn"], rms_norm(x, p["norm1"], cfg.norm_eps), positions,
-            window=window if window is not None else cfg.attn_window)
+            window=window if window is not None else cfg.attn_window,
+            contiguous=contiguous)
         h = rms_norm(x, p["norm2"], cfg.norm_eps)
         if cfg.family == "moe":
             bsz, s, d = h.shape
@@ -91,7 +93,7 @@ def _block(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     elif cfg.family == "hybrid":
         x = x + hybrid.hybrid_forward(
             cfg, p["hyb"], rms_norm(x, p["norm1"], cfg.norm_eps), positions,
-            window=window)
+            window=window, contiguous=contiguous)
         x = x + mlp(cfg, p["mlp"], rms_norm(x, p["norm2"], cfg.norm_eps))
     if cfg.seq_shard_acts and x.shape[-2] > 1:
         # Megatron-style sequence parallelism: the residual stream lives
@@ -126,12 +128,13 @@ def forward(cfg: ModelConfig, params: dict, inputs: jax.Array,
     post-final-norm hidden states with ``return_hidden`` (for the
     vocab-streaming chunked-CE loss, which never materializes logits)."""
     x = embed_inputs(cfg, params, inputs)
-    if positions is None:
+    contiguous = positions is None
+    if contiguous:
         positions = jnp.broadcast_to(jnp.arange(x.shape[-2]), x.shape[:-1])
 
     def body(carry, layer_p):
         h, aux = carry
-        h, a = _block(cfg, layer_p, h, positions, window)
+        h, a = _block(cfg, layer_p, h, positions, window, contiguous)
         return (h, aux + a), None
 
     if remat:

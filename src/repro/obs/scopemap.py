@@ -40,6 +40,25 @@ def latest():
     return _latest
 
 
+def _lines(hlo_text: str):
+    """The HLO text's lines, an instruction whose attributes run over
+    several lines (a Pallas kernel's ``kernel_metadata``) joined into one:
+    its ``op_name`` follows them."""
+    held = None
+    for line in hlo_text.splitlines():
+        if held is not None:
+            held += line
+            if held.count("{") <= held.count("}"):
+                yield held
+                held = None
+        elif line.startswith(" ") and line.count("{") > line.count("}"):
+            held = line
+        else:
+            yield line
+    if held is not None:
+        yield held
+
+
 def parse(hlo_text: str) -> Dict[str, str]:
     """{instruction name: op_name} of an HLO module's text.  An instruction
     the compiler made without an ``op_name`` (a loop's layout copy) takes
@@ -48,7 +67,7 @@ def parse(hlo_text: str) -> Dict[str, str]:
     out."""
     own, home, caller = {}, {}, {}
     comp = None
-    for line in hlo_text.splitlines():
+    for line in _lines(hlo_text):
         if not line.startswith(" "):
             m = _HEADER.match(line)
             if m and line.rstrip().endswith("{"):
@@ -86,3 +105,21 @@ def op_paths() -> Optional[Dict[str, str]]:
     if _paths is None:
         _paths = parse(_latest.as_text())
     return _paths
+
+
+def attention_executor(paths: Optional[Dict[str, str]] = None
+                       ) -> Optional[Dict[str, object]]:
+    """Which attention core the newest recorded round (or ``paths``, an
+    ``op_paths``/``parse`` result) runs: ``{"executor": "flash", "kernels":
+    n}`` for n flash (``splash_*``) kernel instructions under the
+    ``attention`` scope, ``"dense"`` for an ``attention`` scope without
+    them, ``"none"`` for a round without attention; None where no round
+    was compiled."""
+    paths = op_paths() if paths is None else paths
+    if paths is None:
+        return None
+    scoped = [name for name, path in paths.items()
+              if "attention" in path.split("/")]
+    n = sum(name.startswith("splash_") for name in scoped)
+    executor = "flash" if n else ("dense" if scoped else "none")
+    return {"executor": executor, "kernels": n}

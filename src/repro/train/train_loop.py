@@ -111,11 +111,19 @@ def make_train_step(model_cfg: ModelConfig, vrl_cfg: VRLConfig,
     fp32 buffer at 256k vocab).  ``mesh``/``worker_axes`` only affect the
     fused backend (shard_map worker axis for the flat all-reduce)."""
     alg = get_algorithm(vrl_cfg.algorithm)
+    # XLA cannot partition a Pallas kernel: where the model runs
+    # partitioned over a mesh of several devices, explicit positions keep
+    # attention on its dense core (attention.attend_full)
+    partitioned = mesh is not None and mesh.size > 1
 
     @jax.named_scope("model")
     def loss_fn(params, tokens, labels):
+        positions = (jnp.broadcast_to(jnp.arange(tokens.shape[1]),
+                                      tokens.shape[:2])
+                     if partitioned else None)
         if chunked_ce:
             hidden, aux = transformer.forward(model_cfg, params, tokens,
+                                              positions=positions,
                                               remat=remat, unroll=unroll,
                                               return_hidden=True)
             head = (params["embed"] if model_cfg.tie_embeddings
@@ -125,6 +133,7 @@ def make_train_step(model_cfg: ModelConfig, vrl_cfg: VRLConfig,
                 head_is_embed=model_cfg.tie_embeddings)
         else:
             logits, aux = transformer.forward(model_cfg, params, tokens,
+                                              positions=positions,
                                               remat=remat, unroll=unroll)
             loss = cross_entropy_lm(logits, labels)
         if model_cfg.num_experts:

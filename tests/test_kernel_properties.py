@@ -17,13 +17,14 @@ from repro.kernels.ref import flash_attention_ref, ssd_ref
        seed=st.integers(0, 1000))
 def test_mha_flash_matches_reference(b, s, h, kv_ratio, d, window, seed):
     """Arbitrary (non-aligned!) shapes: the wrapper pads to block multiples
-    and must still match plain softmax attention exactly."""
+    and must still match plain softmax attention exactly; kv heads are
+    read in groups, not repeated."""
     nh = h * kv_ratio
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, s, nh, d))
     k = jax.random.normal(ks[1], (b, s, h, d))
     v = jax.random.normal(ks[2], (b, s, h, d))
-    out = ops.mha_flash(q, k, v, window=window, block_q=32, block_k=32)
+    out = ops.mha_flash(q, k, v, window=window, block_q=128, block_k=128)
     kk = jnp.repeat(k, kv_ratio, axis=2)
     vv = jnp.repeat(v, kv_ratio, axis=2)
     ref = flash_attention_ref(

@@ -35,7 +35,7 @@ def test_flash_attention_window(window):
     key = jax.random.PRNGKey(0)
     q, k, v = (jax.random.normal(kk, (2, 256, 64))
                for kk in jax.random.split(key, 3))
-    out = flash_attention(q, k, v, window=window, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, window=window, block_q=128, block_k=128)
     ref = flash_attention_ref(q, k, v, window=window)
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
@@ -44,9 +44,46 @@ def test_flash_attention_block_shape_independence():
     key = jax.random.PRNGKey(3)
     q, k, v = (jax.random.normal(kk, (2, 256, 64))
                for kk in jax.random.split(key, 3))
-    o1 = flash_attention(q, k, v, block_q=64, block_k=128)
-    o2 = flash_attention(q, k, v, block_q=128, block_k=64)
+    o1 = flash_attention(q, k, v, block_q=128, block_k=256)
+    o2 = flash_attention(q, k, v, block_q=256, block_k=128)
     assert float(jnp.max(jnp.abs(o1 - o2))) < 2e-5
+
+
+def _dense_gqa(q, k, v, window):
+    """(B, S, H, D) q, (B, S, KVH, D) k, v through the plain oracle, kv
+    heads repeated to H."""
+    b, s, h, d = q.shape
+    group = h // k.shape[2]
+    heads = lambda t: jnp.moveaxis(t, 2, 1).reshape(b * h, s, d)  # noqa: E731
+    out = flash_attention_ref(heads(q), heads(jnp.repeat(k, group, axis=2)),
+                              heads(jnp.repeat(v, group, axis=2)),
+                              window=window)
+    return jnp.moveaxis(out.reshape(b, h, s, d), 1, 2)
+
+
+@pytest.mark.parametrize("h,kvh", [(14, 2), (32, 8)])
+@pytest.mark.parametrize("window", [None, 96])
+def test_flash_attention_gradients_gqa(h, kvh, window):
+    """dq, dk, dv of the kernel's backward (kv heads read in groups of 7
+    and 4, not repeated) against jax.grad of the dense oracle; S=200 is
+    padded to the block."""
+    ks = jax.random.split(jax.random.PRNGKey(h + (window or 0)), 4)
+    b, s, d = 1, 200, 64
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, kvh, d))
+    v = jax.random.normal(ks[2], (b, s, kvh, d))
+    g = jax.random.normal(ks[3], (b, s, h, d))
+
+    def grads(core):
+        return jax.grad(lambda q, k, v: (core(q, k, v) * g).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: ops.mha_flash(q, k, v, window=window))
+    want = grads(lambda q, k, v: _dense_gqa(q, k, v, window))
+    for name, a, r in zip("qkv", got, want):
+        assert a.shape == r.shape, name
+        err = float(jnp.max(jnp.abs(a - r)) / jnp.max(jnp.abs(r)))
+        assert err < 2e-5, (name, err)
 
 
 @pytest.mark.parametrize("b,l,h,p,n,chunk", [

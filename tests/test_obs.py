@@ -169,6 +169,46 @@ def test_scopemap_parses_op_names_of_compiled_hlo():
         "t": "jit(traced)/model/while"}
 
 
+def test_scopemap_reads_a_kernel_whose_attributes_span_lines():
+    """A Pallas kernel with ``kernel_metadata`` prints its attributes over
+    several lines, the ``op_name`` on the last; ``parse`` reads it there,
+    and ``attention_executor`` counts the flash kernels under
+    ``attention``."""
+    from repro.obs import scopemap
+    attn = "jit(r)/while/body/cond/branch_0_fun/attention/vmap(jit(s))"
+    text = "\n".join([
+        "%body.2 (t: (s32[], f32[8])) -> (s32[], f32[8]) {",
+        "  %splash_mha_fwd_residuals.21 = (bf16[2,14,2048,64]{3,2,1,0}) "
+        'custom-call(%a, %b), custom_call_target="tpu_custom_call", '
+        "frontend_attributes={kernel_metadata={",
+        '"xprof_metadata":"{\\"block_q\\": 128, \\"block_kv\\": 128}"',
+        f'}}}}, metadata={{op_name="{attn}/splash_mha_fwd_residuals/'
+        'pallas_call" stack_frame_id=81}, backend_config={"k": "v"}',
+        "  %splash_mha_dq_no_residuals.11 = bf16[2,14,2048,64]{3,2,1,0} "
+        "custom-call(%a), frontend_attributes={kernel_metadata={",
+        '"xprof_metadata":"{}"',
+        f'}}}}, metadata={{op_name="{attn}/splash_mha_dq_no_residuals/'
+        'pallas_call"}',
+        '  %fusion.9 = f32[8]{0} fusion(%a), kind=kLoop, calls=%f.1, '
+        'metadata={op_name="jit(r)/while/body/head/dot_general"}',
+        '  ROOT %tuple.1 = (s32[], f32[8]) tuple(%i, %fusion.9), '
+        'metadata={op_name="jit(r)/while/body/model/add"}',
+        "}"])
+    paths = scopemap.parse(text)
+    assert paths["splash_mha_fwd_residuals.21"] == (
+        f"{attn}/splash_mha_fwd_residuals/pallas_call")
+    assert paths["splash_mha_dq_no_residuals.11"] == (
+        f"{attn}/splash_mha_dq_no_residuals/pallas_call")
+    assert paths["fusion.9"] == "jit(r)/while/body/head/dot_general"
+    assert scopemap.attention_executor(paths) == {"executor": "flash",
+                                                  "kernels": 2}
+    dense = {"fusion.1": "jit(r)/model/attention/dot_general"}
+    assert scopemap.attention_executor(dense) == {"executor": "dense",
+                                                  "kernels": 0}
+    assert scopemap.attention_executor({"fusion.9": paths["fusion.9"]}) == {
+        "executor": "none", "kernels": 0}
+
+
 def test_round_cache_records_its_executable():
     """Each round executable ``RoundCache`` compiles becomes
     ``scopemap.latest()``, and ``op_paths`` reads its scopes."""
@@ -355,6 +395,8 @@ def test_training_run_emits_documented_stream(tmp_path):
     assert end["event"] == "run_end" and end["steps"] == 4
     assert np.isfinite(end["avg_model_loss"])
     assert end["phases"]["round"]["n"] == 2
+    # the CPU lowering runs the dense attention core
+    assert end["attention"] == {"executor": "dense", "kernels": 0}
     # --loss-out and the stream agree, and the reporter renders it
     assert json.load(open(lo))["avg_model_loss"] == end["avg_model_loss"]
     text = report.summarize(recs)

@@ -35,7 +35,7 @@ P_, D_ = 2, 1               # hierarchical grid: 2 pods of 1 worker
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -49,9 +49,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _w(*lead, rows=R, lanes=C):
@@ -168,3 +173,71 @@ def test_round_compiles_for_v5e_at_published_widths(one_chip):
                           ("vrl_sync", "engine.sync")):
         line = _kernel_line(hlo, kernel)
         assert line and f"{scope}/{kernel}/pallas_call" in line, kernel
+
+
+def test_round_runs_flash_attention_for_v5e(one_chip):
+    """The same two-layer qwen2-0.5b round at 2 x 2048 tokens: attention
+    runs as the splash kernels (forward, and one backward for dq, dk, dv)
+    inside the ``attention`` scope, and no (…, 2048, 2048) fp32 score
+    tensor is left."""
+    from repro.obs import scopemap
+
+    cfg = dataclasses.replace(registry.get_arch("qwen2-0.5b"), num_layers=2)
+    vrl = VRLConfig(algorithm="vrl_sgd", comm_period=2, learning_rate=0.05,
+                    warmup=False, update_backend="fused",
+                    engine=EngineConfig(interpret=False))
+    bundle = make_train_step(cfg, vrl)
+    state = jax.eval_shape(lambda key: bundle.init_state(key, 1),
+                           jax.random.PRNGKey(0))
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), state)
+    toks = jax.ShapeDtypeStruct((2, 1, 2, 2048), jnp.int32,
+                                sharding=one_chip)
+    hlo = jax.jit(bundle.round_step, donate_argnums=(0,)).lower(
+        state, toks, toks).compile().as_text()
+    paths = scopemap.parse(hlo)
+    # the forward, and the fused backward that gives dq, dk and dv
+    kernels = {"splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals"}
+    found = {n for n in paths if n.split(".")[0] in kernels}
+    assert {n.split(".")[0] for n in found} == kernels
+    for name in found:
+        assert _kernel_line(hlo, name.split(".")[0]), name
+        assert "/attention/" in paths[name], (name, paths[name])
+    # the forward twice (the forward pass and its remat), the backward
+    # kernels once, all placed under ``attention``
+    assert scopemap.attention_executor(paths) == {"executor": "flash",
+                                                  "kernels": len(found)}
+    assert len(found) == 3
+    assert not re.search(r"f32\[[\d,]*2048,2048\]", hlo)
+
+
+def test_mesh_round_keeps_dense_attention_for_v5e(topo):
+    """Two workers on a two-chip mesh at 1024 tokens: the model runs
+    partitioned by XLA, which cannot partition a Pallas kernel, so the
+    round compiles with the dense attention core."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import engine as engine_mod
+    from repro.launch import mesh as mesh_mod
+
+    mesh = mesh_mod.make_engine_mesh(2, devices=list(topo.devices))
+    cfg = dataclasses.replace(registry.get_arch("qwen2-0.5b"), num_layers=2,
+                              d_model=128, num_heads=4, num_kv_heads=2,
+                              d_ff=256, vocab_size=512)
+    vrl = VRLConfig(algorithm="vrl_sgd", comm_period=2, learning_rate=0.05,
+                    warmup=False, update_backend="fused",
+                    engine=EngineConfig(interpret=False))
+    axes = ("pod", "data")
+    bundle = make_train_step(cfg, vrl, mesh=mesh, worker_axes=axes)
+    state = jax.eval_shape(lambda key: bundle.init_state(key, 2),
+                           jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        state, engine_mod.state_partition_specs(state, axes))
+    toks = jax.ShapeDtypeStruct((2, 2, 1, 1024), jnp.int32,
+                                sharding=NamedSharding(mesh, P(None, axes)))
+    hlo = jax.jit(bundle.round_step, donate_argnums=(0,)).lower(
+        state, toks, toks).compile().as_text()
+    assert "splash_mha" not in hlo
+    assert _kernel_line(hlo, "vrl_local_sgd")
