@@ -2,7 +2,27 @@
 gives it: ``configs/<config>.json``, ``traffic/<traffic>.json``,
 ``limits/<cell>.json``, ``metrics/<metric>.py`` and
 ``reference/<reference>.py``, named by the traffic file or else by the
-configuration.  A later cell adds files; none is edited."""
+configuration.  A later cell adds files; none is edited.
+
+A reference module is the plain float32 model and algorithm, and the
+model's counts.  It gives:
+
+    dims(cfg)                  the sizes it reads from a configuration's
+                               published keys
+    seed_key(seed)             a PRNG key from any whole-number seed
+    params_from_key(dims, key) the benchmark's weights (traceable)
+    init_params(dims, seed)    the same, made in one jitted call
+    check(vrl)                 ValueError where it cannot follow the
+                               program's ``VRLConfig``
+    Reference(dims, vrl, *, workers, devices, dtype, half_batch,
+              no_exchange)     ``.run(params0, rounds)``: the readings
+    param_count(dims)          every trained parameter
+    train_flops_per_token(dims, seq)
+                               FLOP one token needs in a training step
+
+and may give ``make_readout`` and ``compare`` (``cell.Program``).  A
+configuration of another architecture brings its own module, counts
+included (``counts``)."""
 from __future__ import annotations
 
 import importlib.util
@@ -66,11 +86,12 @@ def _module(path: Path, name: str):
     return mod
 
 
-def reference(cfg: dict, traffic: dict):
+def reference(cfg: dict, traffic: dict | None = None):
     """The plain reference module that the traffic file names (a program
     setting of its own, such as another algorithm, needs a reference of
-    its own), or else the one the configuration names."""
-    name = traffic.get("reference", cfg["reference"])
+    its own), or else, and with no traffic, the one the configuration
+    names."""
+    name = (traffic or {}).get("reference", cfg["reference"])
     return _module(BENCH / "reference" / f"{name}.py", f"bench_ref_{name}")
 
 

@@ -122,6 +122,21 @@ def per_device(ctx: dict) -> dict:
     return ctx["scope_seconds"]
 
 
+def ops_under(ctx: dict, pattern: str, scope: str):
+    """A pattern for ``xtrace`` that matches exactly the traced operations
+    whose name matches ``pattern`` and whose path lies under the ``TOP``
+    scope ``scope`` (the sync's all-reduce, not the loss mean's); None
+    where no such operation ran, or the program keeps no map."""
+    paths = program_paths() or {}
+    rx = re.compile(pattern)
+    names = sorted({e[0] for ops in ctx["trace"]["devices"].values()
+                    for e in ops if rx.search(e[0])
+                    and top_scope(paths.get(instruction(e[0]), "")) == scope})
+    if not names:
+        return None
+    return "^(?:" + "|".join(map(re.escape, names)) + ")$"
+
+
 def ms_per_round(ctx: dict, scope: str):
     """Device ms under ``scope`` per traced round, the largest over the
     chips used; None where no operation of the scope ran (as in a program

@@ -1,14 +1,17 @@
 """The part of the sync's all-reduce per round during which no other
-operation ran on that chip, in ms, the largest over the chips used."""
+operation ran on that chip, in ms, the largest over the chips used: the
+all-reduce operations under the named scope ``engine.sync``, as
+``sync.allreduce_ms`` selects them."""
 
-from benchlib import xtrace
+from benchlib import scopes, xtrace
 
 ALLREDUCE = r"^all-reduce"
 
 
 def read(ctx):
-    tr = ctx["trace"]
-    if not any(xtrace.op_count(tr, d, ALLREDUCE) for d in tr["devices"]):
+    pattern = scopes.ops_under(ctx, ALLREDUCE, "engine.sync")
+    if pattern is None:
         return None
-    return max(1e3 * xtrace.exposed_s(tr, dev, ALLREDUCE)
+    tr = ctx["trace"]
+    return max(1e3 * xtrace.exposed_s(tr, dev, pattern)
                / ctx["rounds_traced"] for dev in tr["devices"])

@@ -128,8 +128,31 @@ def init_params(m: Dims, seed: int):
 
 
 def param_count(m: Dims) -> int:
+    """Every trained parameter: the leaves this reference makes, the
+    tied embedding once."""
     return int(sum(np.prod(s) for s, _, _ in jax.tree.leaves(
         shapes(m), is_leaf=_is_shape)))
+
+
+def matmul_params(m: Dims) -> int:
+    """Weights that enter a matrix product per token: the attention
+    projections, the SwiGLU matrices and the tied head.  The embedding
+    gather is no product; biases and norm scales are not matrices."""
+    q = m.d * m.heads * m.head_dim
+    kv = 2 * m.d * m.kv_heads * m.head_dim
+    o = m.heads * m.head_dim * m.d
+    mlp = 3 * m.d * m.ff
+    return m.layers * (q + kv + o + mlp) + m.vocab * m.d
+
+
+def train_flops_per_token(m: Dims, seq: int) -> int:
+    """FLOP one token needs in a training step, forward and backward:
+    6 per matmul weight, plus causal attention's 6 * L * S * H * hd
+    (scores and the weighted sum, each 2 * S * H * hd for the full
+    square forward, halved by the causal mask, tripled by the backward).
+    Recomputation under remat is not required, so it is not counted."""
+    return (6 * matmul_params(m)
+            + 6 * m.layers * seq * m.heads * m.head_dim)
 
 
 # ------------------------------------------------------------------ forward

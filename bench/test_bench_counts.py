@@ -140,3 +140,112 @@ def test_run_refuses_with_the_benchmark_alone(tmp_path):
     p = _cli(tmp_path)
     assert p.returncode != 0
     assert not any(line.startswith("{") for line in p.stdout.splitlines())
+
+
+class TwoKinds:
+    """A toy reference of a hybrid with two layer kinds: in each period of
+    ``period`` layers, one attention layer as the dense block has it and
+    ``period - 1`` gated mixers (in_proj d -> 2e, out_proj e -> d, and a
+    linear scan of 6 * e * state FLOP a token in training), each layer
+    followed by a SwiGLU MLP; a tied head."""
+
+    @staticmethod
+    def dims(cfg):
+        return cfg
+
+    @staticmethod
+    def _layers(m):
+        attn = m["num_hidden_layers"] // m["period"]
+        return attn, m["num_hidden_layers"] - attn
+
+    @staticmethod
+    def matmul_params(m):
+        d, hd = m["hidden_size"], m["head_dim"]
+        attn, mix = TwoKinds._layers(m)
+        qkvo = (2 * m["num_attention_heads"]
+                + 2 * m["num_key_value_heads"]) * d * hd
+        return (attn * qkvo + mix * 3 * d * m["expand"]
+                + m["num_hidden_layers"] * 3 * d * m["intermediate_size"]
+                + m["vocab_size"] * d)
+
+    @staticmethod
+    def param_count(m):
+        d = m["hidden_size"]
+        return (TwoKinds.matmul_params(m) + 2 * m["num_hidden_layers"] * d
+                + d)
+
+    @staticmethod
+    def train_flops_per_token(m, seq):
+        attn, mix = TwoKinds._layers(m)
+        return (6 * TwoKinds.matmul_params(m)
+                + 6 * attn * seq * m["num_attention_heads"] * m["head_dim"]
+                + 6 * mix * m["expand"] * m["state"])
+
+
+def _two_kinds(monkeypatch):
+    """The small configuration, named to the toy reference, which
+    ``files.reference`` hands out for it."""
+    from benchlib import small
+    cfg = dict(small.config(), reference="two_kinds", num_hidden_layers=8,
+               period=4, expand=128, state=16)
+    real = files.reference
+    monkeypatch.setattr(files, "reference", lambda c, traffic=None: (
+        TwoKinds if c["reference"] == "two_kinds" else real(c, traffic)))
+    return cfg
+
+
+# by hand, for d 64, 4/2 heads of 16, ff 128, vocab 512, 8 layers in two
+# periods of 1 attention + 3 mixers (e 128, state 16):
+#   attention q, k, v, o: (4 + 4 + 2 + 2) * 16 * 64 = 12,288, x 2 layers
+#   mixers: 3 * 64 * 128 = 24,576, x 6; MLPs 3 * 64 * 128, x 8; head
+#   512 * 64 = 32,768: matmul weights 24,576 + 147,456 + 196,608 + 32,768
+TWO_KINDS_MATMUL = 401_408
+TWO_KINDS_PARAMS = TWO_KINDS_MATMUL + 2 * 8 * 64 + 64   # norms
+TWO_KINDS_FLOPS_512 = (6 * TWO_KINDS_MATMUL + 6 * 2 * 512 * 4 * 16
+                       + 6 * 6 * 128 * 16)
+
+
+def test_counts_come_from_the_configurations_reference(monkeypatch):
+    """A configuration whose reference has two layer kinds is counted by
+    that reference: in ``counts``, in the traced context that
+    ``round.mfu`` reads, and in ``engine.update_roofline``'s bytes, not
+    by the dense formula."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchlib import cell, scopes
+    cfg = _two_kinds(monkeypatch)
+    assert counts.matmul_params(cfg) == TWO_KINDS_MATMUL
+    assert counts.param_count(cfg) == TWO_KINDS_PARAMS
+    assert counts.train_flops_per_token(cfg, 512) == TWO_KINDS_FLOPS_512
+    dense = files.reference(dict(cfg, reference="dense"))
+    m = dense.dims(cfg)
+    assert dense.param_count(m) != TWO_KINDS_PARAMS
+    assert dense.train_flops_per_token(m, 512) != TWO_KINDS_FLOPS_512
+
+    peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    monkeypatch.setattr(files, "peaks", lambda kind: peaks)
+    t = {"workers": 4, "seq": 512, "k": 2}
+    step = jax.jit(lambda s, toks, labels: (s + 1, jnp.sum(toks)))
+    ctx = cell.traced_segment(
+        jnp.zeros(()), step, lambda r: (jnp.full((4,), r), None), 0, t,
+        cfg, jax.devices()[:1], 1.0, lambda s: None)
+    assert ctx["flops_per_token"] == TWO_KINDS_FLOPS_512
+    ctx.update(tokens_per_s=10_000.0, chips=4)
+    assert files.metric_reader("round.mfu")(ctx) == pytest.approx(
+        100 * 10_000.0 * TWO_KINDS_FLOPS_512 / (4 * 197e12))
+    monkeypatch.setattr(scopes, "ms_per_round", lambda ctx, scope: 2.0)
+    # k x W / chips x parameters x 16 B over 2 ms at 819 GB/s
+    assert files.metric_reader("engine.update_roofline")(ctx) == \
+        pytest.approx(100 * 2 * 1 * TWO_KINDS_PARAMS * 16 / (2e-3 * 819e9))
+
+
+@pytest.mark.parametrize("count,args", [("param_count", ()),
+                                        ("train_flops_per_token", (2048,))])
+def test_a_reference_without_counts_is_an_error(monkeypatch, count, args):
+    import types
+    bare = types.ModuleType("bench_ref_bare")
+    bare.dims = lambda cfg: cfg
+    monkeypatch.setattr(files, "reference", lambda c, traffic=None: bare)
+    with pytest.raises(files.BenchError, match="bench_ref_bare.*" + count):
+        getattr(counts, count)(_cfg("qwen2-0.5b"), *args)

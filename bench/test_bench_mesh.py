@@ -1,15 +1,18 @@
-"""A four-worker cell's whole run on four virtual CPU devices, one
-worker per device over the engine mesh: clean, it is correct; with the
-exchange between workers left out (each worker's mean is itself), it is
-not.  Runs in a child process, since the device count is fixed when JAX
-starts.  The limits are those a four-chip qwen2-0.5b cell at 512 tokens
-read on the chip with the rotary base at 1e4; a cell that the benchmark
-runs takes its own from ``limits/``."""
+"""The four-chip cell's whole run on four virtual CPU devices, one worker
+per device over the engine mesh, under the cell's own limits: clean, it
+is correct; with the timed path broken underneath, it is not, for each
+fault the cell can have: the exchange between workers left out (each
+worker's mean is itself), a round that returns its state unchanged, and
+half of each worker's batch left out (its one row's first half of
+positions, the mean taken over the rest).  Runs in a child process, since
+the device count is fixed when JAX starts."""
 import json
 import os
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 BENCH = Path(__file__).resolve().parent
 
@@ -25,15 +28,27 @@ if fault == "no_exchange":
     real = jax.lax.psum
     jax.lax.psum = lambda x, axis_name, **kw: jax.tree.map(
         lambda a: a * real(1, axis_name), x)
-c = {"name": "qwen2-0.5b.w4.s512.k2", "config": "qwen2-0.5b",
-     "traffic": "w4.b1.s512.k2", "chips": 4}
-limits = {k: {"limit": v} for k, v in dict(
-    loss=3.3e-4, update=0.041, update2=0.044, delta=0.056, delta_sum=0.016,
-    drift=0, nonfinite_rounds=0, window_compiles=0).items()}
-out = cell.run(files.benchmark(), c, 3_000_000_031, 0.5, False,
+elif fault == "unchanged":
+    build = cell.build
+
+    def broken(cfg, t, devs):
+        bundle, vrl = build(cfg, t, devs)
+        real_step = bundle.round_step
+        return bundle._replace(round_step=lambda state, toks, labels: (
+            state, real_step(state, toks, labels)[1])), vrl
+    cell.build = broken
+elif fault == "half_batch":
+    from repro.train import train_loop
+    ce = train_loop.cross_entropy_lm
+    train_loop.cross_entropy_lm = lambda logits, labels: ce(
+        logits[..., :logits.shape[-2] // 2, :],
+        labels[..., :labels.shape[-1] // 2])
+spec = files.benchmark()
+c = files.cell(spec, "qwen2-0.5b.w4.s512.k2")
+out = cell.run(spec, c, 3_000_000_031, 0.5, False,
                t0=time.perf_counter(), devices=jax.devices(),
                cfg=small.config(), traffic=small.traffic(4, batch=1),
-               limits=limits, log=lambda s: None)
+               log=lambda s: None)
 print(json.dumps(out))
 """
 
@@ -57,3 +72,10 @@ def test_four_workers_clean_and_without_exchange():
     assert not broken["correct"], broken["checks"]
     assert broken["checks"]["delta"]["value"] > broken["checks"]["delta"][
         "limit"]
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_four_workers_broken_round_is_not_correct(fault):
+    out = _child(fault)
+    assert out["device"]["count"] == 4
+    assert not out["correct"], out["checks"]

@@ -1,6 +1,8 @@
 """The plain reference against the program's round at a small size on
 the CPU, and the control (the reference in bfloat16) against the limits
 of the cells."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,15 +37,25 @@ def test_reference_follows_the_program_round(batch, k):
     assert all(v < 1e-5 for v in nums.values()), nums
 
 
-def test_control_fails_the_comparison():
+@pytest.mark.parametrize("workers,name", [(1, "qwen2-0.5b.s2048.k8"),
+                                          (4, "qwen2-0.5b.w4.s512.k2")])
+def test_control_fails_the_comparison(workers, name):
     """The reference computed in bfloat16 fails at least one of the
-    numbers a one-worker cell compares, at that cell's limits."""
-    t = small.traffic()
-    prog, mk, p0, rounds = _program_and_reference(t)
+    numbers a cell of one or of four workers compares, at that cell's
+    limits."""
+    t = small.traffic(workers, batch=2 if workers == 1 else 1)
+    cfg = small.config()
+    ref = files.reference(cfg, t)
+    m = ref.dims(cfg)
+    mk = functools.partial(ref.Reference, m, cell.vrl_config(t),
+                           workers=workers, devices=jax.devices()[:1])
+    pool = traffic_mod.token_pool(t, m.vocab, SEED)
+    rounds = [pool[i] for i in range(t["follow"])]
+    p0 = ref.init_params(m, SEED)
     ref32 = mk().run(p0, rounds)
     ctrl = mk(dtype=jnp.bfloat16).run(p0, rounds)
     nums = cell.compare(cell.as_program(ctrl), ref32, t)
-    lim = files.limits({"name": "qwen2-0.5b.s2048.k8"})
+    lim = files.limits({"name": name})
     assert any(nums[k] > lim[k]["limit"] for k in nums if k in lim), (
         nums, lim)
     assert np.all(np.isfinite(ctrl.losses))

@@ -98,13 +98,26 @@ def test_recorded_chip_trace_hand_counts():
     assert xtrace.top_ops(t, 1)[0][0].startswith("custom-call:traced")
 
 
-def test_recorded_allreduce_hand_counts():
+# the all-reduces' paths as the program's map gives them on a four-worker
+# mesh: the sync's psum under ``engine.sync``, the loss mean's under none
+SYNC_PSUM = "jit(traced)/shard_map/engine.sync/psum"
+LOSS_MEAN = "jit(traced)/while/body/closed_call/reduce_sum"
+
+
+def _program_paths(monkeypatch, paths):
+    from benchlib import scopes
+    monkeypatch.setattr(scopes, "program_paths", lambda: paths)
+
+
+def test_recorded_allreduce_hand_counts(monkeypatch):
     """0.4 ms around a sync all-reduce on four TPU v5e chips
     (qwen2-0.5b.w4.s512.k2), chips 0 and 1: the psum of the 1930240 x 256
     flat buffer runs 34,696,404 ns on chip 0 and 34,730,812 ns on chip 1
     with nothing beside it but the enclosing loop, so all of it is
-    exposed; each chip is idle 19 ns of the 35,096,404 ns window."""
+    exposed; each chip is idle 19 ns of the 35,096,404 ns window.  The
+    sync readers find the psum by its path under ``engine.sync``."""
     from benchlib import files
+    _program_paths(monkeypatch, {"psum.7": SYNC_PSUM})
     with open(files.BENCH / "testdata" / "allreduce.json") as f:
         t = json.load(f)
     assert xtrace.op_time_s(t, "0", "^all-reduce") == pytest.approx(
@@ -122,3 +135,29 @@ def test_recorded_allreduce_hand_counts():
         34.730812)
     assert files.metric_reader("device.idle_share")(ctx) == pytest.approx(
         100 * 19 / 35_096_404)
+
+
+def test_sync_readers_count_only_the_sync_allreduce(monkeypatch):
+    """Two chips, each running the sync's all-reduce (100 ns, 20 and 11 of
+    them beside a fusion) and the loss mean's (40 and 50 ns): the readers
+    count the sync's alone.  Without a scope map, or where no all-reduce
+    lies under ``engine.sync`` (one worker), they read nothing."""
+    from benchlib import files
+    ops = lambda d: [                                       # noqa: E731
+        ["all-reduce:psum.7:f32[8]", 100.0 + d, 100.0],
+        ["fusion:fusion.3:f32[8]", 180.0 + 10 * d, 40.0],
+        ["all-reduce:all-reduce.12:f32[]", 400.0, 40.0 + 10 * d]]
+    t = {"window": [0.0, 1000.0], "devices": {"0": ops(0), "1": ops(1)},
+         "host": []}
+    ctx = {"trace": t, "rounds_traced": 2}
+    read = lambda name: files.metric_reader(name)(ctx)     # noqa: E731
+    _program_paths(monkeypatch, {"psum.7": SYNC_PSUM,
+                                 "all-reduce.12": LOSS_MEAN,
+                                 "fusion.3": "jit(traced)/model/add"})
+    # 100 ns over 2 rounds; exposed: chip 0 80 ns, chip 1 89 ns
+    assert read("sync.allreduce_ms") == pytest.approx(50e-6)
+    assert read("sync.exposed_ms") == pytest.approx(44.5e-6)
+    for paths in (None, {"all-reduce.12": LOSS_MEAN}):
+        _program_paths(monkeypatch, paths)
+        assert read("sync.allreduce_ms") is None
+        assert read("sync.exposed_ms") is None
